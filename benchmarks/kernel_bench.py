@@ -79,13 +79,14 @@ def _paged_decode_rows(rng, n: int, k: int, pool_factor: int = 64,
     def pr3_gather(pool, sel):  # the four pool-wide transposes, verbatim
         phys = resolve_logical_rows(pool, sel.indices)
 
-        def take_codes(codes):
-            flat = codes.reshape((-1,) + codes.shape[2:])
+        def take_codes(codes):  # (P, KV, BS, HD) → flat (P·BS, KV, HD)
+            flat = codes.swapaxes(1, 2).reshape((-1,) + codes.shape[1:2]
+                                                + codes.shape[3:])
             f = flat.transpose(1, 0, 2)
             return jnp.take_along_axis(f[None], phys[..., None], axis=2)
 
         def take_scale(scale):
-            flat = scale.reshape((-1,) + scale.shape[2:])
+            flat = scale.swapaxes(1, 2).reshape(-1, scale.shape[1])
             f = flat.transpose(1, 0)
             return jnp.take_along_axis(f[None], phys, axis=2)
 
@@ -192,7 +193,7 @@ def _sharded_decode_rows(rng, n: int, k: int, gate: bool = False) -> list[str]:
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core import (SalcaParams, empty_paged_cache, prefill_cache,
                             prefill_into_pages)
     from repro.core.performance_model import (
@@ -214,13 +215,13 @@ def _sharded_decode_rows(rng, n: int, k: int, gate: bool = False) -> list[str]:
     pages[:need] = rng.choice(num_blocks, need, replace=False)
     pool = prefill_into_pages(pool, dense, 0, jnp.asarray(pages))
     q = jnp.asarray(rng.normal(size=(1, 2 * kv, hd)), jnp.float32)
-    mesh = compat.make_mesh((1,), ("seq",))
+    mesh = make_mesh((1,), ("seq",))
 
     def island(fused):
         def f(q_, p_):
             return sp_salca_decode_paged(q_, p_, params, "seq", fused=fused)
-        return jax.jit(compat.shard_map(f, mesh, in_specs=(P(), P()),
-                                        out_specs=P(), check_vma=False))
+        return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                     out_specs=P(), check_vma=False))
 
     leg = sharded_gather_bytes_per_token(
         n=n, d=hd, kv_heads=kv, groups=2, s_f=0.5, retention=k / n,

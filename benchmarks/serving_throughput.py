@@ -498,7 +498,7 @@ def _sharded_sweep(cfg, params, smoke: bool):
     """Admitted long-context concurrency vs pool shard count, at a fixed
     per-device pool size — the capacity claim of the sharded page pools —
     plus the sharded-vs-unsharded greedy parity gate."""
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.models.blocks import DecodeCtx
     from repro.runtime.serve import Request, ServingEngine
 
@@ -527,7 +527,7 @@ def _sharded_sweep(cfg, params, smoke: bool):
     for shards in ((1, 4) if smoke else (1, 2, 4)):
         ctx = None
         if shards > 1:
-            mesh = compat.make_mesh((shards,), ("seq",))
+            mesh = make_mesh((shards,), ("seq",))
             ctx = DecodeCtx(axis="seq", mesh=mesh)
         eng = ServingEngine(cfg, params, max_seq=MAX_SEQ, slots=slots,
                             ctx=ctx, paged=True, block_size=BLOCK_SIZE,

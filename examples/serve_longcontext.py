@@ -205,7 +205,7 @@ def _sharded_demo(args, cfg, params) -> None:
     import jax
     import numpy as np
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.models.blocks import DecodeCtx
     from repro.runtime.serve import Request, ServingEngine
 
@@ -224,7 +224,7 @@ def _sharded_demo(args, cfg, params) -> None:
     for shards in (1, args.shards):
         ctx = None
         if shards > 1:
-            mesh = compat.make_mesh((shards,), ("seq",))
+            mesh = make_mesh((shards,), ("seq",))
             ctx = DecodeCtx(axis="seq", mesh=mesh)
         engine = ServingEngine(cfg, params, max_seq=max_seq, slots=1,
                                ctx=ctx, paged=True, block_size=bs,

@@ -60,7 +60,10 @@ def exact_sparse_attention(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
     kv = k_codes.shape[1]
     g = h // kv
     qg = q.reshape(b, kv, g, hd).astype(jnp.float32)
-    s_int = jnp.einsum("bkgd,bkcd->bkgc", qg, k_codes.astype(jnp.float32))
+    # Full f32 matmuls: the TPU default for f32 operands rounds them to bf16.
+    hi = jax.lax.Precision.HIGHEST
+    s_int = jnp.einsum("bkgd,bkcd->bkgc", qg, k_codes.astype(jnp.float32),
+                       precision=hi)
     s = s_int * k_scale[:, :, None, :] / jnp.sqrt(hd).astype(jnp.float32)
     s = jnp.where(mask[:, :, None, :], s, NEG_INF)
     # Safe softmax with global-max tracking (paper's qk_max mechanism).
@@ -70,7 +73,7 @@ def exact_sparse_attention(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
     p = jnp.where(mask[:, :, None, :], p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     v = v_codes.astype(jnp.float32) * v_scale[..., None]      # (B,KV,C,HD)
-    o = jnp.einsum("bkgc,bkcd->bkgd", p, v) / jnp.maximum(l, 1e-20)
+    o = jnp.einsum("bkgc,bkcd->bkgd", p, v, precision=hi) / jnp.maximum(l, 1e-20)
     return o.reshape(b, h, hd)
 
 

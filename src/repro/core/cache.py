@@ -245,13 +245,17 @@ class PagedSalcaCache(NamedTuple):
     # Physical pool, shared by all slots (no batch dim). The K/V region is
     # stored at `kv_pool_dtype` precision (inferred from the leaves, see
     # below); the feature stream is always the packed 2-bit layout:
-    k_codes: jax.Array     # (P, BS, KV, HD) int8 | f16 | (P, BS, KV, HD//2) int4-packed
-    k_scale: jax.Array     # (P, BS, KV) f32 per-token | (P, 1, KV) per-block
-    v_codes: jax.Array     # (P, BS, KV, HD) int8 | f16 | (P, BS, KV, HD//2) int4-packed
-    v_scale: jax.Array     # (P, BS, KV) f32 per-token | (P, 1, KV) per-block
-    feat_words: jax.Array  # (P, BS, KV, R//16) uint32
-    feat_scale: jax.Array  # (P, BS, KV) f32
-    feat_zero: jax.Array   # (P, BS, KV) f32
+    # The kv-head axis leads the token axis in every data leaf, so one
+    # (block, head) pair is a (BS, ·) tile whose last two dims a TPU kernel
+    # can stream alone — the exact-attention kernel fetches only the heads a
+    # selection named, never the whole block.
+    k_codes: jax.Array     # (P, KV, BS, HD) int8 | f16 | (P, KV, BS, HD//2) int4-packed
+    k_scale: jax.Array     # (P, KV, BS) f32 per-token | (P, KV, 1) per-block
+    v_codes: jax.Array     # (P, KV, BS, HD) int8 | f16 | (P, KV, BS, HD//2) int4-packed
+    v_scale: jax.Array     # (P, KV, BS) f32 per-token | (P, KV, 1) per-block
+    feat_words: jax.Array  # (P, KV, BS, R//16) uint32
+    feat_scale: jax.Array  # (P, KV, BS) f32
+    feat_zero: jax.Array   # (P, KV, BS) f32
     # Per-slot request state:
     heavy_idx: jax.Array   # (S, KV, R) int32 — frozen heavy-channel set
     length: jax.Array      # (S,) int32 — tokens currently stored
@@ -270,14 +274,14 @@ class PagedSalcaCache(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k_codes.shape[-3]
+        return self.k_codes.shape[-2]
 
     @property
     def kv_pool_dtype(self) -> str:
         """K/V storage precision, inferred from the leaves (kept out of the
         pytree so the NamedTuple stays a plain jit-safe container):
 
-        * ``float16`` codes → "fp16" (unit scales, shape (P, 1, KV))
+        * ``float16`` codes → "fp16" (unit scales, shape (P, KV, 1))
         * int8 codes with per-token scales (scale dim == block_size) → "int8"
         * int8 codes with per-block scales (scale dim == 1) → "int4"
           (two signed nibbles per byte along head_dim)
@@ -286,7 +290,7 @@ class PagedSalcaCache(NamedTuple):
         the scale-dim test is unambiguous."""
         if self.k_codes.dtype == jnp.float16:
             return "fp16"
-        if self.k_scale.shape[-2] == self.k_codes.shape[-3]:
+        if self.k_scale.shape[-1] == self.k_codes.shape[-2]:
             return "int8"
         return "int4"
 
@@ -305,7 +309,7 @@ class PagedSalcaCache(NamedTuple):
 
     @property
     def num_kv_heads(self) -> int:
-        return self.k_codes.shape[-2]
+        return self.k_codes.shape[-3]
 
     @property
     def head_dim(self) -> int:
@@ -512,23 +516,23 @@ def empty_paged_cache(num_blocks: int, block_size: int, slots: int,
                       r: int, kv_pool_dtype: str = "int8") -> PagedSalcaCache:
     zeros = lambda shape, dt: jnp.zeros(shape, dt)
     if kv_pool_dtype == "int8":
-        code_shape = (num_blocks, block_size, kv_heads, head_dim)
+        code_shape = (num_blocks, kv_heads, block_size, head_dim)
         code_dt = jnp.int8
         # Per-token scales, zero-init (never read before written).
-        scale = zeros((num_blocks, block_size, kv_heads), jnp.float32)
+        scale = zeros((num_blocks, kv_heads, block_size), jnp.float32)
     elif kv_pool_dtype == "fp16":
         assert block_size > 1, "fp16 pool needs block_size > 1 (mode inference)"
-        code_shape = (num_blocks, block_size, kv_heads, head_dim)
+        code_shape = (num_blocks, kv_heads, block_size, head_dim)
         code_dt = jnp.float16
         # Unit per-block scales: the dequant paths multiply by them blindly,
         # so they MUST be ones (and nothing ever rewrites them).
-        scale = jnp.ones((num_blocks, 1, kv_heads), jnp.float32)
+        scale = jnp.ones((num_blocks, kv_heads, 1), jnp.float32)
     elif kv_pool_dtype == "int4":
         assert block_size > 1, "int4 pool needs block_size > 1 (mode inference)"
         assert head_dim % 2 == 0, f"head_dim {head_dim} not packable to int4"
-        code_shape = (num_blocks, block_size, kv_heads, head_dim // 2)
+        code_shape = (num_blocks, kv_heads, block_size, head_dim // 2)
         code_dt = jnp.int8
-        scale = zeros((num_blocks, 1, kv_heads), jnp.float32)
+        scale = zeros((num_blocks, kv_heads, 1), jnp.float32)
     else:
         raise ValueError(f"unknown kv_pool_dtype {kv_pool_dtype!r}")
     return PagedSalcaCache(
@@ -536,10 +540,10 @@ def empty_paged_cache(num_blocks: int, block_size: int, slots: int,
         k_scale=scale,
         v_codes=zeros(code_shape, code_dt),
         v_scale=scale,
-        feat_words=zeros((num_blocks, block_size, kv_heads, r // qz.CODES_PER_WORD),
+        feat_words=zeros((num_blocks, kv_heads, block_size, r // qz.CODES_PER_WORD),
                          jnp.uint32),
-        feat_scale=zeros((num_blocks, block_size, kv_heads), jnp.float32),
-        feat_zero=zeros((num_blocks, block_size, kv_heads), jnp.float32),
+        feat_scale=zeros((num_blocks, kv_heads, block_size), jnp.float32),
+        feat_zero=zeros((num_blocks, kv_heads, block_size), jnp.float32),
         heavy_idx=zeros((slots, kv_heads, r), jnp.int32),
         length=zeros((slots,), jnp.int32),
         page_table=jnp.full((slots, max_blocks), PAGE_UNMAPPED, jnp.int32),
@@ -622,9 +626,9 @@ def prefill_into_pages(pool: PagedSalcaCache, src: SalcaCache, slot,
     writable = jnp.arange(mb) >= jnp.asarray(n_shared, jnp.int32)
     safe_pages = jnp.where((pages >= 0) & writable, pages, p)  # → OOB → dropped
 
-    def to_blocks(val):  # val: (1, src_seq, KV, ·) → (MB, BS, KV, ·)
+    def to_blocks(val):  # val: (1, src_seq, KV, ·) → (MB, KV, BS, ·)
         v = jnp.pad(val[0], ((0, pad),) + ((0, 0),) * (val.ndim - 2))
-        return v.reshape((mb, bs) + v.shape[1:])
+        return v.reshape((mb, bs) + v.shape[1:]).swapaxes(1, 2)
 
     def upd(buf, blocks):
         return buf.at[safe_pages].set(blocks.astype(buf.dtype), mode="drop")
@@ -643,12 +647,12 @@ def prefill_into_pages(pool: PagedSalcaCache, src: SalcaCache, slot,
         v = to_blocks(src.v_codes).astype(jnp.float32) * to_blocks(src.v_scale)[..., None]
         if mode == "fp16":
             kc, vc = k, v                               # cast to f16 in `upd`
-            ks = vs = jnp.ones((mb, 1, pool.num_kv_heads), jnp.float32)
+            ks = vs = jnp.ones((mb, pool.num_kv_heads, 1), jnp.float32)
         else:                                           # int4
-            kq, ks = qz.sym_quantize_axes(k, bits=4, axes=(1, 3))
-            vq, vs = qz.sym_quantize_axes(v, bits=4, axes=(1, 3))
+            kq, ks = qz.sym_quantize_axes(k, bits=4, axes=(2, 3))
+            vq, vs = qz.sym_quantize_axes(v, bits=4, axes=(2, 3))
             kc, vc = qz.pack_int4(kq), qz.pack_int4(vq)
-            ks, vs = ks[..., 0], vs[..., 0]             # (MB, 1, KV)
+            ks, vs = ks[..., 0], vs[..., 0]             # (MB, KV, 1)
 
     return pool._replace(
         k_codes=upd(pool.k_codes, kc),
@@ -735,9 +739,11 @@ def prefill_chunk_into_pages(pool: PagedSalcaCache, k: jax.Array, v: jax.Array,
     writable = (pg >= 0) & (blk >= jnp.asarray(n_shared, jnp.int32))
     tgt_pg = jnp.where(writable, pg, p)                          # OOB → dropped
     off = rows % bs
+    kvb = jnp.arange(pool.num_kv_heads)[None, :]
 
     def upd(buf, vals):  # vals: (1, C, KV, ·) per-token field values
-        return buf.at[tgt_pg, off].set(vals[0].astype(buf.dtype), mode="drop")
+        return buf.at[tgt_pg[:, None], kvb, off[:, None]].set(
+            vals[0].astype(buf.dtype), mode="drop")
 
     if mode == "int8":
         kc, ks = k8.codes, k8.scale
@@ -817,9 +823,15 @@ def append_token_paged(pool: PagedSalcaCache, k: jax.Array, v: jax.Array,
     off = cur % bs
     k8, v8, words, fs, fz = _encode_tokens(k[:, None], v[:, None], pool.heavy_idx)
 
-    def upd(buf, val):  # scatter each slot's row at (block, offset) directly —
-        # no flat (P·BS, ·) reshape of the pool enters the decode tick
-        return buf.at[pg, off].set(val[:, 0].astype(buf.dtype), mode="drop")
+    kvb = jnp.arange(pool.num_kv_heads)[None, :]
+
+    def upd(buf, val):  # scatter each slot's rows at (block, head, offset)
+        # directly — no flat (P·BS, ·) reshape of the pool enters the decode
+        # tick. Indexing all three leading dims keeps the scatter in the
+        # pool's own layout (a (block, :, offset) scatter makes XLA relayout
+        # the whole pool around it).
+        return buf.at[pg[:, None], kvb, off[:, None]].set(
+            val[:, 0].astype(buf.dtype), mode="drop")
 
     mode = pool.kv_pool_dtype
     if mode == "int8":
@@ -852,10 +864,10 @@ def _int4_block_append(codes_buf, scale_buf, tok, pg, off):
     inherit a stale scale, or visible codes would depend on pool history.
     ``pg`` carries the out-of-bounds drop sentinel for gated slots; gathers
     clamp it to 0 (their result is discarded by the dropped scatter)."""
-    p, bs = codes_buf.shape[0], codes_buf.shape[1]
+    p, bs = codes_buf.shape[0], codes_buf.shape[2]
     pg_safe = jnp.where(pg < p, pg, 0)
-    old_codes = qz.unpack_int4(codes_buf[pg_safe])             # (S, BS, KV, HD)
-    old_scale = scale_buf[pg_safe, 0]                          # (S, KV)
+    old_codes = qz.unpack_int4(codes_buf[pg_safe])             # (S, KV, BS, HD)
+    old_scale = scale_buf[pg_safe, :, 0]                       # (S, KV)
     t32 = tok.astype(jnp.float32)                              # (S, KV, HD)
     amax = jnp.max(jnp.abs(t32), axis=-1)                      # (S, KV)
     reset = (off == 0)[:, None]
@@ -864,12 +876,13 @@ def _int4_block_append(codes_buf, scale_buf, tok, pg, off):
     ratio = jnp.where(reset, 0.0, old_scale / new_scale)
     m = qz.INT4_MAXABS
     rescaled = jnp.clip(jnp.round(old_codes.astype(jnp.float32)
-                                  * ratio[:, None, :, None]), -m, m)
+                                  * ratio[:, :, None, None]), -m, m)
     tok_codes = jnp.clip(jnp.round(t32 / new_scale[..., None]), -m, m)
-    row = jnp.arange(bs)[None, :, None, None] == off[:, None, None, None]
-    merged = jnp.where(row, tok_codes[:, None], rescaled).astype(jnp.int8)
+    row = jnp.arange(bs)[None, None, :, None] == off[:, None, None, None]
+    merged = jnp.where(row, tok_codes[:, :, None], rescaled).astype(jnp.int8)
+    kvb = jnp.arange(scale_buf.shape[1])[None, :]
     return (codes_buf.at[pg].set(qz.pack_int4(merged), mode="drop"),
-            scale_buf.at[pg, 0].set(new_scale, mode="drop"))
+            scale_buf.at[pg[:, None], kvb, 0].set(new_scale, mode="drop"))
 
 
 def map_block(pool: PagedSalcaCache, slot, logical_block, page,
@@ -982,9 +995,9 @@ def paged_logical_features(pool: PagedSalcaCache):
     pt = pool.clamped_pages()                                   # (S, MB)
     s, l = pt.shape[0], pool.max_seq
 
-    def logical(buf):  # (P, BS, KV, ·) → (S, L, KV, ·)
-        g = buf[pt]                                             # (S, MB, BS, KV, ·)
-        return g.reshape((s, l) + buf.shape[2:])
+    def logical(buf):  # (P, KV, BS, ·) → (S, L, KV, ·)
+        g = buf[pt].swapaxes(2, 3)                              # (S, MB, BS, KV, ·)
+        return g.reshape((s, l, buf.shape[1]) + buf.shape[3:])
 
     return (logical(pool.feat_words), logical(pool.feat_scale),
             logical(pool.feat_zero))
@@ -996,16 +1009,17 @@ def paged_logical_kv(pool: PagedSalcaCache):
     the selected-gather path for the sparse decode.
 
     Mode-generic: int4 codes unpack first, and the scale gather broadcasts
-    whether it is per-token ``(·, BS, KV)`` or per-block ``(·, 1, KV)`` —
+    whether it is per-token ``(·, KV, BS)`` or per-block ``(·, KV, 1)`` —
     the fp16 pool's unit scales make the multiply an exact identity."""
     pt = pool.clamped_pages()
     s, l = pt.shape[0], pool.max_seq
     unpack = qz.unpack_int4 if pool.kv_pool_dtype == "int4" else (lambda x: x)
-    k = (unpack(pool.k_codes[pt]).astype(jnp.float32)
-         * pool.k_scale[pt][..., None]).reshape(s, l, pool.num_kv_heads, -1)
-    v = (unpack(pool.v_codes[pt]).astype(jnp.float32)
-         * pool.v_scale[pt][..., None]).reshape(s, l, pool.num_kv_heads, -1)
-    return k, v
+
+    def logical(codes, scale):  # (S, MB, KV, BS, HD) → (S, L, KV, HD)
+        x = unpack(codes[pt]).astype(jnp.float32) * scale[pt][..., None]
+        return x.swapaxes(2, 3).reshape(s, l, pool.num_kv_heads, -1)
+
+    return logical(pool.k_codes, pool.k_scale), logical(pool.v_codes, pool.v_scale)
 
 
 def _resolve_pages(pool: PagedSalcaCache, idx: jax.Array, block_range=None):
@@ -1047,8 +1061,8 @@ def gather_selected_paged(pool: PagedSalcaCache, sel, block_range=None) -> tuple
     and scales (S, KV, C) — the same contract as `attention.gather_selected`.
 
     The page-table resolution is computed ONCE and each field is fetched with
-    a single (block, offset, kv-head) advanced-index gather straight off the
-    `(P, BS, KV, ·)` pool — no `(P·BS, KV, ·)` flattening and no pool-wide
+    a single (block, kv-head, offset) advanced-index gather straight off the
+    `(P, KV, BS, ·)` pool — no `(P·BS, KV, ·)` flattening and no pool-wide
     transpose ever materializes (the PR 3 form transposed all four pool
     buffers every decode tick). Unmapped resolutions clamp to (block 0,
     offset 0); callers mask them.
@@ -1063,17 +1077,17 @@ def gather_selected_paged(pool: PagedSalcaCache, sel, block_range=None) -> tuple
 
     mode = pool.kv_pool_dtype
     if mode == "int8":
-        return (pool.k_codes[pg, off, kvb], pool.k_scale[pg, off, kvb],
-                pool.v_codes[pg, off, kvb], pool.v_scale[pg, off, kvb])
+        return (pool.k_codes[pg, kvb, off], pool.k_scale[pg, kvb, off],
+                pool.v_codes[pg, kvb, off], pool.v_scale[pg, kvb, off])
     # Per-block scales: one scale row per block, fetched at scale-offset 0
     # and broadcast across the block's gathered tokens; int4 codes unpack to
     # full head_dim so the consumer contract is unchanged.
     soff = jnp.zeros_like(off)
-    kc, vc = pool.k_codes[pg, off, kvb], pool.v_codes[pg, off, kvb]
+    kc, vc = pool.k_codes[pg, kvb, off], pool.v_codes[pg, kvb, off]
     if mode == "int4":
         kc, vc = qz.unpack_int4(kc), qz.unpack_int4(vc)
-    return (kc, pool.k_scale[pg, soff, kvb],
-            vc, pool.v_scale[pg, soff, kvb])
+    return (kc, pool.k_scale[pg, kvb, soff],
+            vc, pool.v_scale[pg, kvb, soff])
 
 
 def record_selection(pool: PagedSalcaCache, sel_indices: jax.Array,

@@ -166,12 +166,13 @@ def dequant_score_chain(q_scale: jax.Array, a: jax.Array, z: jax.Array,
 
     All relevance-score producers (flat XLA, paged XLA, paged Pallas kernel)
     run THIS function so their scores are bit-identical by construction.
-    When ``bf16`` (§Perf it-6) the chain emulates bf16 arithmetic in f32 via
-    ``lax.reduce_precision`` after every op: a plain bf16 dtype chain rounds
-    per-op in eager mode but XLA fusion may elide the intermediate rounding,
-    making numerics depend on the surrounding graph — reduce_precision is
-    never elided, so the rounding points are pinned no matter how each
-    caller's graph compiles. Operands must be pre-broadcast; returns f32.
+    When ``bf16`` (§Perf it-6) the chain emulates bf16 arithmetic in f32 by
+    rounding to bf16 precision after every op (`round_to_bf16`): a plain
+    bf16 dtype chain rounds per-op in eager mode but XLA fusion may elide the
+    intermediate rounding, making numerics depend on the surrounding graph —
+    integer bit rounding is never elided, so the rounding points are pinned
+    no matter how each caller's graph compiles, and it lowers inside a TPU
+    kernel too. Operands must be pre-broadcast; returns f32.
     """
     d = int_dot.astype(jnp.float32)
     qm = q_sums.astype(jnp.float32)
@@ -181,10 +182,19 @@ def dequant_score_chain(q_scale: jax.Array, a: jax.Array, z: jax.Array,
     if not bf16:
         return qs * (a * d + z * qm)
 
-    def rp(t):
-        return jax.lax.reduce_precision(t, exponent_bits=8, mantissa_bits=7)
-
+    rp = round_to_bf16
     return rp(rp(qs) * rp(rp(rp(a) * rp(d)) + rp(rp(z) * rp(qm))))
+
+
+def round_to_bf16(x: jax.Array) -> jax.Array:
+    """Round f32 to the nearest bf16 value (ties to even), kept in f32.
+
+    Equal to ``lax.reduce_precision(x, 8, 7)`` on finite inputs, written with
+    integer ops on the bit pattern because Pallas TPU kernels have no
+    lowering for reduce_precision."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    b = (b + 0x7FFF + ((b >> 16) & 1)) & jnp.int32(-65536)
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
 
 
 SCORE_NEG_INF = -3.0e38     # masked-score sentinel for the binning affine map

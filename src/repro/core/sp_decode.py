@@ -36,7 +36,6 @@ from repro.core.selection import (
     SalcaParams, estimate_relevance, estimate_relevance_paged_bounds,
     query_heavy_features)
 from repro.core.attention import gather_selected, NEG_INF
-from repro import compat
 
 _EPS = 1e-6
 
@@ -47,7 +46,7 @@ def _halo_exchange(x: jax.Array, halo: int, axis_name) -> jax.Array:
     x: (..., n_local). Returns (..., n_local + 2*halo) with edge fill 0
     (the minimum INT8 bin) at the global boundaries.
     """
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     left_edge = x[..., -halo:]    # what our LEFT neighbour needs on its right
     right_edge = x[..., :halo]
@@ -146,7 +145,7 @@ def sp_salca_decode(q: jax.Array, cache: SalcaCache, params: SalcaParams,
     kv = cache.num_kv_heads
     groups = h // kv
     n_local = cache.max_seq
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     if shard_cap is None:
         shard_cap = min(n_local, max(128, (4 * params.k_cap) // max(n_shards, 1)))
 
@@ -243,15 +242,15 @@ def _shard_pool_view(pool: PagedSalcaCache, axis_name):
 
 def _local_logical(pool: PagedSalcaCache, local_pt: jax.Array):
     """Gather a block-indexed pool leaf into logical order from the LOCAL
-    pool: buf (P_local, BS, KV, ·) → (S, L, KV, ·). Unowned blocks read
+    pool: buf (P_local, KV, BS, ·) → (S, L, KV, ·). Unowned blocks read
     local block 0 (masked by the caller); owned blocks land bit-identical
     to the flat `paged_logical_features` gather."""
     s, mb = local_pt.shape
     l = mb * pool.block_size
 
     def logical(buf):
-        g = buf[local_pt]                                   # (S, MB, BS, KV, ·)
-        return g.reshape((s, l) + buf.shape[2:])
+        g = buf[local_pt].swapaxes(2, 3)                    # (S, MB, BS, KV, ·)
+        return g.reshape((s, l, buf.shape[1]) + buf.shape[3:])
 
     return logical
 
@@ -581,12 +580,12 @@ def sp_dense_decode_paged(q: jax.Array, pool: PagedSalcaCache, axis_name,
         valid = valid & (pos > (global_pos[:, None] - window))
     pgk, offk = pg[:, None, :], off[:, None, :]                    # (S, 1, L)
     kvb = jnp.arange(kv)[None, :, None]                            # (1, KV, 1)
-    kc, vc = pool.k_codes[pgk, offk, kvb], pool.v_codes[pgk, offk, kvb]
+    kc, vc = pool.k_codes[pgk, kvb, offk], pool.v_codes[pgk, kvb, offk]
     mode = pool.kv_pool_dtype
     if mode == "int4":
         kc, vc = qz.unpack_int4(kc), qz.unpack_int4(vc)
     soff = offk if mode == "int8" else jnp.zeros_like(offk)
-    ks, vs = pool.k_scale[pgk, soff, kvb], pool.v_scale[pgk, soff, kvb]
+    ks, vs = pool.k_scale[pgk, kvb, soff], pool.v_scale[pgk, kvb, soff]
     kk = kc.astype(jnp.float32) * ks[..., None]                    # (S,KV,L,HD)
     vv = vc.astype(jnp.float32) * vs[..., None]
     qg = q.reshape(s_, kv, groups, hd).astype(jnp.float32)

@@ -86,8 +86,7 @@ def make_compressed_grad_fn(loss_fn, mesh, dp_axis: str = "data"):
         return jax.tree.map(lambda _: spec, tree)
 
     def build(params_shape, batch_shape, errors_shape):
-        from repro.compat import shard_map
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(specs_like(params_shape, P()),
                       specs_like(batch_shape, P(dp_axis)),

@@ -16,11 +16,11 @@ SUBLANE = 8
 
 
 def interpret_default() -> bool:
-    """True when running without a TPU (kernels execute in interpret mode)."""
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # pragma: no cover - device probing should not fail
-        return True
+    """True when running without a TPU (kernels execute in interpret mode).
+
+    A failed device probe raises: silently interpreting would hide a broken
+    accelerator behind a slow CPU emulation."""
+    return jax.devices()[0].platform != "tpu"
 
 
 def round_up(x: int, m: int) -> int:

@@ -33,8 +33,8 @@ def sparse_flash_decode_paged_ref(q: jax.Array, k_codes: jax.Array,
                                   kv_dtype: str = "int8") -> jax.Array:
     """Paged-native oracle: same contract as the scalar-prefetch kernel.
 
-    Fetches each row's listed physical blocks with one (block, token,
-    kv-head) advanced-index gather per field — O(selected blocks), never a
+    Fetches each row's listed physical blocks with one (block, kv-head,
+    token) advanced-index gather per field — O(selected blocks), never a
     flat (P·BS, ·) view of the pool — then runs the flat oracle over the
     flattened (BH, NSB·BS) block stream.
 
@@ -44,21 +44,21 @@ def sparse_flash_decode_paged_ref(q: jax.Array, k_codes: jax.Array,
     flat oracle sees them.
     """
     bh = q.shape[0]
-    bs = k_codes.shape[1]
+    bs = k_codes.shape[2]
     nsb = pblk.shape[1]
     kvb = (jnp.arange(bh) % num_kv)[:, None, None]             # (BH, 1, 1)
     tok = jnp.arange(bs)[None, None, :]                        # (1, 1, BS)
     pb = pblk[:, :, None]                                      # (BH, NSB, 1)
-    kc = k_codes[pb, tok, kvb]                                 # (BH, NSB, BS, ·)
-    vc = v_codes[pb, tok, kvb]
+    kc = k_codes[pb, kvb, tok]                                 # (BH, NSB, BS, ·)
+    vc = v_codes[pb, kvb, tok]
     if kv_dtype == "int4":
         from repro.core import quantization as qz
         kc, vc = qz.unpack_int4(kc), qz.unpack_int4(vc)
     kc = kc.reshape(bh, nsb * bs, -1)
     vc = vc.reshape(bh, nsb * bs, -1)
     stok = tok if kv_dtype == "int8" else jnp.zeros_like(tok)
-    ks = k_scale[pb, stok, kvb].reshape(bh, nsb * bs)
-    vs = v_scale[pb, stok, kvb].reshape(bh, nsb * bs)
+    ks = k_scale[pb, kvb, stok].reshape(bh, nsb * bs)
+    vs = v_scale[pb, kvb, stok].reshape(bh, nsb * bs)
     return sparse_flash_decode_ref(q, kc, ks, vc, vs,
                                    blk_mask.reshape(bh, nsb * bs))
 
@@ -96,20 +96,20 @@ def sparse_flash_decode_paged_partials_ref(q: jax.Array, k_codes: jax.Array,
     followed by the unnormalized flat oracle — the reference for the
     shard-local leg of the sharded fused tick."""
     bh = q.shape[0]
-    bs = k_codes.shape[1]
+    bs = k_codes.shape[2]
     nsb = pblk.shape[1]
     kvb = (jnp.arange(bh) % num_kv)[:, None, None]
     tok = jnp.arange(bs)[None, None, :]
     pb = pblk[:, :, None]
-    kc = k_codes[pb, tok, kvb]
-    vc = v_codes[pb, tok, kvb]
+    kc = k_codes[pb, kvb, tok]
+    vc = v_codes[pb, kvb, tok]
     if kv_dtype == "int4":
         from repro.core import quantization as qz
         kc, vc = qz.unpack_int4(kc), qz.unpack_int4(vc)
     kc = kc.reshape(bh, nsb * bs, -1)
     vc = vc.reshape(bh, nsb * bs, -1)
     stok = tok if kv_dtype == "int8" else jnp.zeros_like(tok)
-    ks = k_scale[pb, stok, kvb].reshape(bh, nsb * bs)
-    vs = v_scale[pb, stok, kvb].reshape(bh, nsb * bs)
+    ks = k_scale[pb, kvb, stok].reshape(bh, nsb * bs)
+    vs = v_scale[pb, kvb, stok].reshape(bh, nsb * bs)
     return sparse_flash_decode_partials_ref(q, kc, ks, vc, vs,
                                             blk_mask.reshape(bh, nsb * bs))

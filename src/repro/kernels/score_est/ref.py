@@ -39,15 +39,15 @@ def paged_score_estimate_ref(q_codes: jax.Array, q_scale: jax.Array,
     """
     s, kv, g, r = q_codes.shape
     mb = pages.shape[1]
-    bs = feat_words.shape[1]
-    fw = feat_words[pages]                                    # (S, MB, BS, KV, W)
+    bs = feat_words.shape[2]
+    fw = feat_words[pages]                                    # (S, MB, KV, BS, W)
     # kv-head leading on both operands → a clean batched int matmul (the
     # mixed-order contraction lowers ~3× slower on CPU).
-    codes = qz.unpack2bit(fw, r).transpose(0, 3, 1, 2, 4)     # (S, KV, MB, BS, r)
+    codes = qz.unpack2bit(fw, r).transpose(0, 2, 1, 3, 4)     # (S, KV, MB, BS, r)
     int_dot = jnp.einsum("skgr,skmnr->skgmn", q_codes, codes,
                          preferred_element_type=jnp.int32)    # (S, KV, G, MB, BS)
-    a = feat_scale[pages].transpose(0, 3, 1, 2)[:, :, None]
-    z = feat_zero[pages].transpose(0, 3, 1, 2)[:, :, None]
+    a = feat_scale[pages].transpose(0, 2, 1, 3)[:, :, None]
+    z = feat_zero[pages].transpose(0, 2, 1, 3)[:, :, None]
     scores = qz.dequant_score_chain(q_scale[..., None, None], a, z, int_dot,
                                     q_sums[..., None, None], bf16)
     return jnp.sum(scores, axis=2, dtype=jnp.float32).reshape(s, kv, mb * bs)

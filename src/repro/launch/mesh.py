@@ -1,12 +1,22 @@
-"""Production mesh construction (prescribed shapes).
+"""Mesh construction.
 
-A function, not a module constant: importing this module never touches jax
+Functions, not module constants: importing this module never touches jax
 device state (device count is locked at first use).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """`jax.make_mesh` with Auto axis types: the programs here place their
+    shardings through in/out specs and shard_map, never through explicit
+    sharding-in-types (jax's default)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,13 +24,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (2, 16, 16) = ("pod", "data", "model") — 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    from repro.compat import make_mesh
     return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """All locally-visible devices as (1, N) ("data", "model") — used by
-    smoke tests and examples (N=1 on this CPU container)."""
-    n = len(jax.devices())
-    from repro.compat import make_mesh
-    return make_mesh((1, n), ("data", "model"))
+    smoke tests and examples."""
+    return make_mesh((1, len(jax.devices())), ("data", "model"))

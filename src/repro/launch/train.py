@@ -14,6 +14,7 @@ import argparse
 import logging
 
 from repro.configs import get_config, get_shape
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.runtime.optimizer import AdamWConfig
 from repro.runtime.steps import MeshPlan
@@ -33,6 +34,7 @@ def main() -> None:
                     help="local devices + reduced model/shape (smoke mode)")
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

@@ -69,6 +69,8 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     q: (B, T, H, HD); k, v: (B, S, KV, HD). ``q_offset`` shifts query
     positions (cross-chunk prefill). Returns (B, T, H, HD) in q dtype.
+    A key length S that ``chunk`` does not divide is padded to the next
+    multiple with masked keys (their contributions are exact zeros).
     """
     b, t, h, hd = q.shape
     s = k.shape[1]
@@ -77,8 +79,11 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
         k = jnp.repeat(k, h // kv, axis=2)
         v = jnp.repeat(v, h // kv, axis=2)
     chunk = min(chunk, s)
-    assert s % chunk == 0, f"S={s} not divisible by chunk {chunk}"
-    nc = s // chunk
+    pad = -s % chunk
+    if pad:
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    nc = (s + pad) // chunk
     kc = k.reshape(b, nc, chunk, h, hd).transpose(1, 0, 2, 3, 4)
     vc = v.reshape(b, nc, chunk, h, hd).transpose(1, 0, 2, 3, 4)
     qpos = jnp.asarray(q_offset) + jnp.arange(t)
@@ -101,7 +106,7 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
         else:
             sc = jnp.einsum("bthd,bshd->bhts", qf, kb.astype(jnp.float32)) * scale
         kpos = ci * chunk + jnp.arange(chunk)
-        mask = jnp.ones((t, chunk), bool)
+        mask = jnp.broadcast_to(kpos[None, :] < s, (t, chunk))
         if causal:
             mask &= qpos[:, None] >= kpos[None, :]
         if window > 0:

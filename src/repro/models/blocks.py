@@ -281,6 +281,21 @@ def block_prefill_chunk(params: dict, x: jax.Array, kbuf: jax.Array,
 # Decode: one token
 # ---------------------------------------------------------------------------
 
+def attn_decode_qkv(params: dict, x: jax.Array, cfg: ModelConfig,
+                    pos: jax.Array):
+    """One decode token's projections: q (B, H, HD) f32 and k, v (B, KV, HD)
+    in the compute dtype, qk-normed and rotated to position ``pos``."""
+    q = jnp.einsum("bd,dhk->bhk", x, params["wq"])
+    k = jnp.einsum("bd,dhk->bhk", x, params["wk"])
+    v = jnp.einsum("bd,dhk->bhk", x, params["wv"])
+    if cfg.qk_norm:
+        q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
+        k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
+    q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+    k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+    return q.astype(jnp.float32), k, v
+
+
 def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig,
                  pos: jax.Array, window: int, use_salca: bool,
                  ctx: DecodeCtx, salca: SalcaParams,
@@ -300,16 +315,8 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
     is a well-defined finite value the engine discards.
     """
     b, d = x.shape
-    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    q = jnp.einsum("bd,dhk->bhk", x, params["wq"])
-    k = jnp.einsum("bd,dhk->bhk", x, params["wk"])
-    v = jnp.einsum("bd,dhk->bhk", x, params["wv"])
-    if cfg.qk_norm:
-        q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-    k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-    q = q.astype(jnp.float32)
+    h, hd = cfg.num_heads, cfg.resolved_head_dim
+    q, k, v = attn_decode_qkv(params, x, cfg, pos)
 
     paged = isinstance(cache, PagedSalcaCache)
     ring = (not paged) and window > 0 and cache.max_seq <= window
@@ -344,7 +351,6 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
         # the scalar-prefetched paged kernels; baseline re-materializes the
         # PR 5 O(local pool) logical gathers.
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.core.cache import local_block_range
         from repro.core.sp_decode import (
             sp_dense_decode_paged, sp_salca_decode_paged)
@@ -364,7 +370,7 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
 
         rep3 = P(None, None, None)
         pspec = paged_cache_pspec(ctx)
-        o, cache = shard_map(
+        o, cache = jax.shard_map(
             paged_island, mesh=ctx.mesh,
             in_specs=(rep3, rep3, rep3, P(None), P(None), P(None), pspec),
             out_specs=(rep3, pspec),
@@ -428,9 +434,8 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
                                      global_len=vl_)
             return o_, cache_
 
-        from repro.compat import shard_map
         rep3 = P(ba, None, None)
-        o, cache = shard_map(
+        o, cache = jax.shard_map(
             island, mesh=ctx.mesh,
             in_specs=(rep3, rep3, rep3, P(ba), P(ba), cache_pspec(ctx)),
             out_specs=(rep3, cache_pspec(ctx)),

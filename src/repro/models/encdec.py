@@ -265,8 +265,7 @@ def encdec_decode_step(params: dict, cfg: ModelConfig, state: EncDecState,
                 c_ = sp_append_token(c_, k_, v_, pos_, sa)
                 return sp_dense_decode(q_, c_, sa, global_len=pos_ + 1), c_
 
-            from repro.compat import shard_map
-            o, self_cache = shard_map(
+            o, self_cache = jax.shard_map(
                 island, mesh=ctx.mesh,
                 in_specs=(rep3, rep3, rep3, P(ba), B.cache_pspec(ctx, sa)),
                 out_specs=(rep3, B.cache_pspec(ctx, sa)), check_vma=False,
@@ -296,8 +295,7 @@ def encdec_decode_step(params: dict, cfg: ModelConfig, state: EncDecState,
                     return sp_salca_decode(q_, c_, sp_cross, sa)
                 return sp_dense_decode(q_, c_, sa, global_len=el_)
 
-            from repro.compat import shard_map
-            ox = shard_map(
+            ox = jax.shard_map(
                 island_x, mesh=ctx.mesh,
                 in_specs=(rep3, P(ba), B.cache_pspec(ctx)),
                 out_specs=rep3, check_vma=False,

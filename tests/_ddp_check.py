@@ -9,7 +9,7 @@ import sys
 
 import jax
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -19,7 +19,7 @@ from repro.distributed.compression import compressed_psum
 
 def main() -> int:
     assert len(jax.devices()) == 8
-    mesh = compat.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     dim = 512
     w = jnp.asarray(rng.normal(size=(dim,)), jnp.float32)
@@ -38,10 +38,10 @@ def main() -> int:
         mean, new_err = compressed_psum({"g": g}, "data", {"g": err[0]})
         return mean["g"], new_err["g"][None]
 
-    f_exact = jax.jit(compat.shard_map(
+    f_exact = jax.jit(jax.shard_map(
         exact_step, mesh=mesh, in_specs=(P(), P("data"), P("data")),
         out_specs=P(), check_vma=False))
-    f_comp = jax.jit(compat.shard_map(
+    f_comp = jax.jit(jax.shard_map(
         compressed_step, mesh=mesh,
         in_specs=(P(), P("data"), P("data"), P("data", None)),
         out_specs=(P(), P("data", None)), check_vma=False))

@@ -10,7 +10,7 @@ import tempfile
 
 import jax
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -24,13 +24,13 @@ def main() -> int:
     mgr = CheckpointManager(tmp, async_save=False)
 
     # "training" ran on a (8,) data-only mesh
-    mesh_a = compat.make_mesh((8,), ("data",))
+    mesh_a = make_mesh((8,), ("data",))
     w = jax.device_put(jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                        NamedSharding(mesh_a, P("data", None)))
     mgr.save(7, {"w": w}, block=True)
 
     # restart lands on a (2, 4) data×model mesh — reshard on restore
-    mesh_b = compat.make_mesh((2, 4), ("data", "model"))
+    mesh_b = make_mesh((2, 4), ("data", "model"))
     target = {"w": jnp.zeros((8, 8), jnp.float32)}
     sh = {"w": NamedSharding(mesh_b, P("data", "model"))}
     step, restored = mgr.restore(target, shardings=sh)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import (
     SalcaParams, append_token_paged, empty_paged_cache, prefill_cache,
     prefill_into_pages)
@@ -88,7 +88,7 @@ def check_core_island() -> None:
                                                 return_selection=True)
     ref_dense = dense_decode_from_paged(q, pool)
 
-    mesh = compat.make_mesh((4,), ("seq",))
+    mesh = make_mesh((4,), ("seq",))
     ctx = DecodeCtx(axis="seq", mesh=mesh)
     pspec = paged_cache_pspec(ctx)
     rep = P(None, None, None)
@@ -102,7 +102,7 @@ def check_core_island() -> None:
         return o, od, (sel.indices[None], sel.mask[None], sel.count[None],
                        sel.threshold)
 
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         island, mesh=mesh,
         in_specs=(rep, pspec),
         out_specs=(rep, rep, (P("seq", None, None, None),
@@ -144,7 +144,7 @@ def check_core_island() -> None:
         return append_token_paged(pool_, k_, v_,
                                   block_range=local_block_range(pool_, "seq"))
 
-    sharded = jax.jit(compat.shard_map(
+    sharded = jax.jit(jax.shard_map(
         app_island, mesh=mesh, in_specs=(pspec, rep, rep), out_specs=pspec,
         check_vma=False))(pool, k1, v1)
     for name, a, b in zip(flat._fields, flat, sharded):
@@ -189,7 +189,7 @@ def check_fused_island_parity() -> None:
                          recent_tokens=4)
 
     def island_fn(pool, q, shards, fused, impl=None, interpret=None):
-        mesh = compat.make_mesh((shards,), ("seq",))
+        mesh = make_mesh((shards,), ("seq",))
         pspec = paged_cache_pspec(DecodeCtx(axis="seq", mesh=mesh))
         rep = P(None, None, None)
 
@@ -199,7 +199,7 @@ def check_fused_island_parity() -> None:
                                            impl=impl, interpret=interpret)
             return o, (sel.indices[None], sel.mask[None], sel.threshold)
 
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             island, mesh=mesh, in_specs=(rep, pspec),
             out_specs=(rep, (P("seq", None, None, None),
                              P("seq", None, None, None), P(None, None))),
@@ -264,7 +264,7 @@ def check_engine_parity() -> None:
     def run(paged, shards=1, share=False, fused=None):
         ctx = None
         if shards > 1:
-            mesh = compat.make_mesh((shards,), ("seq",))
+            mesh = make_mesh((shards,), ("seq",))
             ctx = DecodeCtx(axis="seq", mesh=mesh)
         eng = ServingEngine(cfg, params, max_seq=max_seq, slots=4, ctx=ctx,
                             paged=paged, block_size=bs, num_blocks=num_blocks,
@@ -299,7 +299,7 @@ def check_engine_parity() -> None:
 
     # Spanning: a context needing more blocks than one shard holds (8 shards
     # × 3 blocks/shard) must admit by spilling across shards.
-    mesh = compat.make_mesh((8,), ("seq",))
+    mesh = make_mesh((8,), ("seq",))
     eng = ServingEngine(cfg, params, max_seq=max_seq, slots=2,
                         ctx=DecodeCtx(axis="seq", mesh=mesh), paged=True,
                         block_size=bs, num_blocks=num_blocks)
@@ -323,7 +323,7 @@ def check_paged_serve_step() -> None:
 
     cfg = get_config("qwen3-0.6b").reduced()
     shape = ShapeConfig("s", seq_len=128, global_batch=2, kind="decode")
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = MeshPlan.for_mesh(mesh)
     _, jitted, shapes, _ = make_serve_decode_step(cfg, plan, shape, paged=True,
                                                   block_size=16)

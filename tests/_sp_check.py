@@ -12,7 +12,7 @@ import sys
 
 import jax
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -25,7 +25,7 @@ from repro.core.attention import dense_decode_from_cache
 
 def main() -> int:
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     B, T, H, KV, HD = 2, 512, 8, 4, 64
     G = H // KV
@@ -62,7 +62,7 @@ def main() -> int:
         out_dense = sp_dense_decode(q_, c_, "model", global_len=gl_)
         return out_salca, out_dense
 
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         island, mesh=mesh,
         in_specs=(P(None, None, None), P(None), cspec),
         out_specs=(P(None, None, None), P(None, None, None)),
@@ -96,7 +96,7 @@ def main() -> int:
         h = jax.lax.psum(h, "model")
         return locate_threshold(h, params.k)
 
-    t_sp = jax.jit(compat.shard_map(
+    t_sp = jax.jit(jax.shard_map(
         hist_island, mesh=mesh, in_specs=P(None, None, "model"),
         out_specs=P(None, None), check_vma=False))(bins)
     np.testing.assert_array_equal(np.asarray(t_sp), np.asarray(t_global))
@@ -111,7 +111,7 @@ def main() -> int:
         c_ = c_._replace(length=local_lengths(gl_, c_.max_seq, "model"))
         return sp_append_token(c_, k_, v_, gl_, "model")
 
-    new_cache = jax.jit(compat.shard_map(
+    new_cache = jax.jit(jax.shard_map(
         app_island, mesh=mesh,
         in_specs=(cspec, P(None, None, None), P(None, None, None), P(None)),
         out_specs=cspec, check_vma=False))(cache, k_new, v_new, short)

@@ -2,7 +2,7 @@
 
 import jax
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 
@@ -52,7 +52,7 @@ def test_compressed_psum_in_shard_map_degenerate():
     """axis size 1: compressed_psum reduces to quantize+dequantize."""
     from jax.sharding import PartitionSpec as P
     from repro.distributed.compression import compressed_psum
-    mesh = compat.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     g = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(64,)),
                           jnp.float32)}
 
@@ -60,7 +60,7 @@ def test_compressed_psum_in_shard_map_degenerate():
         mean, err = compressed_psum(grads, "d")
         return mean, err
 
-    mean, err = jax.jit(compat.shard_map(
+    mean, err = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=({"w": P()},),
         out_specs=({"w": P()}, {"w": P()}), check_vma=False))(g)
     np.testing.assert_allclose(np.asarray(mean["w"] + err["w"]),

@@ -92,6 +92,23 @@ def test_flash_prefill_matches_xla_path(rng):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_prefill_xla_pads_ragged_keys(rng, causal):
+    """A key length the scan chunk does not divide (a 200-token prompt over
+    64-key chunks) is padded with masked keys: same result as the naive
+    reference, causal or not."""
+    from repro.models.attention import flash_attention_xla
+    bh, t, hd = 2, 200, 64
+    q = jnp.asarray(rng.normal(size=(1, t, bh, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, t, bh, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, t, bh, hd)), jnp.float32)
+    xla = flash_attention_xla(q, k, v, causal=causal, chunk=64)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(bh, t, hd)
+    ref = flash_attention_ref(fold(q), fold(k), fold(v), causal=causal)
+    np.testing.assert_allclose(np.asarray(fold(xla)), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+
+
 @pytest.mark.parametrize("bh,n,window,block", [
     (2, 1024, 7, 512), (1, 4096, 1, 4096), (3, 2048, 11, 1024), (2, 512, 3, 128)])
 def test_selection_fused_sweep(rng, bh, n, window, block):

@@ -141,7 +141,7 @@ def test_prefill_into_pages_and_free(rng):
     # block contents: logical block j lives at physical row pages[j]
     for j in range(-(-t // BS)):
         np.testing.assert_array_equal(
-            np.asarray(pool.k_codes[pages[j]])[: min(BS, t - j * BS)],
+            np.asarray(pool.k_codes[pages[j]]).swapaxes(0, 1)[: min(BS, t - j * BS)],
             np.asarray(dense.k_codes[0, j * BS: min((j + 1) * BS, t)]))
     b = paged_cache_bytes(pool)
     assert b["total"] == b["kv_region"] + b["feature_region"] + b["page_table"]

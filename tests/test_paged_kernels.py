@@ -19,6 +19,7 @@ Covers the acceptance criteria of the kernel-fusion PR:
 import dataclasses
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -213,12 +214,12 @@ def _walk_jaxpr(jaxpr, banned, bad):
                 bad.append((eqn.primitive.name, shape))
         for val in eqn.params.values():
             # shard_map carries an OPEN Jaxpr param; pjit/scan carry Closed.
-            is_jaxpr = lambda x: isinstance(x, (jax.core.Jaxpr,
-                                                jax.core.ClosedJaxpr))
+            is_jaxpr = lambda x: isinstance(x, (jex_core.Jaxpr,
+                                                jex_core.ClosedJaxpr))
             for sub in jax.tree_util.tree_leaves(val, is_leaf=is_jaxpr):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     _walk_jaxpr(sub.jaxpr, banned, bad)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     _walk_jaxpr(sub, banned, bad)
 
 
@@ -229,7 +230,7 @@ def test_fused_tick_has_no_pool_wide_ops(rng, impl):
     logical-order `(S, L, KV, ·)` copy of the feature stream or K/V."""
     _, pool, _ = _scrambled_pool(rng, t=40)
     s = 3
-    p_, bs_, kv_, hd_ = pool.k_codes.shape
+    p_, kv_, bs_, hd_ = pool.k_codes.shape
     l_ = pool.max_seq
     w_ = pool.feat_words.shape[-1]
     banned = {
@@ -268,12 +269,12 @@ def test_sharded_fused_tick_has_no_pool_wide_ops(rng):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core.sp_decode import sp_salca_decode_paged
 
     _, pool, _ = _scrambled_pool(rng, t=40)
     s = 3
-    p_, bs_, kv_, hd_ = pool.k_codes.shape
+    p_, kv_, bs_, hd_ = pool.k_codes.shape
     l_ = pool.max_seq
     w_ = pool.feat_words.shape[-1]
     banned = {
@@ -282,13 +283,13 @@ def test_sharded_fused_tick_has_no_pool_wide_ops(rng):
         (s, l_, kv_, w_), (s, l_, kv_, hd_), (s, l_, kv_),  # logical copies
     }
     q3 = jnp.zeros((s, 4, hd_), jnp.float32)
-    mesh = compat.make_mesh((1,), ("seq",))
+    mesh = make_mesh((1,), ("seq",))
 
     def island(fused):
         def f(q, pool):
             return sp_salca_decode_paged(q, pool, PARAMS, "seq", fused=fused)
-        return compat.shard_map(f, mesh, in_specs=(P(), P()), out_specs=P(),
-                                check_vma=False)
+        return jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                             out_specs=P(), check_vma=False)
 
     bad = []
     _walk_jaxpr(jax.make_jaxpr(island(True))(q3, pool).jaxpr, banned, bad)

@@ -121,10 +121,10 @@ def test_append_is_a_cow_fault_until_serviced(rng):
     stepped = append_token_paged(cowed, k, v)
     assert int(stepped.length[0]) == 7 and int(stepped.length[1]) == 7
     # The shared prefix rows (before the write cursor) are intact in both.
-    np.testing.assert_array_equal(np.asarray(stepped.k_codes[7])[:2],
-                                  before[:2])
-    np.testing.assert_array_equal(np.asarray(stepped.k_codes[2])[:2],
-                                  before[:2])
+    np.testing.assert_array_equal(np.asarray(stepped.k_codes[7])[:, :2],
+                                  before[:, :2])
+    np.testing.assert_array_equal(np.asarray(stepped.k_codes[2])[:, :2],
+                                  before[:, :2])
 
 
 def test_free_pages_decrefs_and_double_free_is_noop(rng):

@@ -133,13 +133,13 @@ def _storage_row(pool, dt, pg, off, h):
     the pool buffers — per-token scales for int8, the block's scale row 0
     for fp16/int4, nibble-unpacked codes for int4."""
     soff = off if dt == "int8" else 0
-    kc = np.asarray(pool.k_codes)[pg, off, h]
-    vc = np.asarray(pool.v_codes)[pg, off, h]
+    kc = np.asarray(pool.k_codes)[pg, h, off]
+    vc = np.asarray(pool.v_codes)[pg, h, off]
     if dt == "int4":
         kc = np.asarray(qz.unpack_int4(jnp.asarray(kc)))
         vc = np.asarray(qz.unpack_int4(jnp.asarray(vc)))
-    return (kc, np.asarray(pool.k_scale)[pg, soff, h],
-            vc, np.asarray(pool.v_scale)[pg, soff, h])
+    return (kc, np.asarray(pool.k_scale)[pg, h, soff],
+            vc, np.asarray(pool.v_scale)[pg, h, soff])
 
 
 @pytest.mark.parametrize("dt", MODES)
@@ -187,6 +187,37 @@ def test_scrambled_pages_invisible_per_mode(rng, dt):
     np.testing.assert_array_equal(np.asarray(sel_a.indices[1]),
                                   np.asarray(sel_b.indices[1]))
     np.testing.assert_allclose(np.asarray(o_a[1]), np.asarray(o_b[1]),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_f16_bit_decode_exact_for_every_pattern():
+    """The flash-decode kernel's in-VMEM fp16 decode (codes arrive as int16
+    bit patterns) equals the f16 → f32 conversion for all 65536 patterns:
+    zeros, subnormals, normals, infinities; NaNs stay NaN."""
+    from repro.kernels.flash_decode.kernel import _f16_bits_to_f32
+    bits = np.arange(-32768, 32768, dtype=np.int16)
+    want = bits.view(np.float16).astype(np.float32)
+    got = np.asarray(_f16_bits_to_f32(jnp.asarray(bits)))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+@pytest.mark.parametrize("dt", MODES)
+def test_paged_flash_kernel_matches_ref_per_mode(rng, dt):
+    """The paged flash-decode kernel (Pallas, interpret) against its blocked
+    XLA oracle on the same selection, in every pool mode."""
+    from repro.kernels.flash_decode.ops import sparse_flash_decode_paged
+    _, pool, _ = _mode_pool(rng, dt)
+    q3 = jnp.asarray(rng.normal(size=(3, 4, 32)), jnp.float32)
+    _, sel = salca_decode_attention_paged(q3, pool, PARAMS,
+                                          return_selection=True)
+    assert int(sel.count.sum()) > 0
+    o_p = sparse_flash_decode_paged(q3, pool, sel, impl="pallas",
+                                    interpret=True)
+    o_r = sparse_flash_decode_paged(q3, pool, sel, impl="ref")
+    np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_r),
                                rtol=1e-5, atol=1e-6)
 
 

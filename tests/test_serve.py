@@ -10,6 +10,8 @@ Covers the acceptance criteria of the slot-pool refactor:
     lengths, stop tokens, and stats bookkeeping.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,25 +161,41 @@ def test_masked_decode_inactive_slot_untouched(api, params, rng):
     assert int(pool2.pos[1]) == 0                   # inactive held
 
 
-def test_pooled_decode_matches_solo(api, params, rng):
+def test_pooled_decode_matches_solo(rng):
     """A slot decoded inside a pool (other slots active on other requests)
-    produces the same logits as the same request decoded at batch=1."""
-    pa, pb = _prompt(rng, 12), _prompt(rng, 20)
+    produces the same logits as the same request decoded at batch=1, and
+    its logits do not depend on what the other slots hold.
+
+    Runs the model in f32: in bf16 the batch-3 and batch-1 programs round
+    differently (one bf16 unit in the last place on some logits, with the
+    slot's logits still bit-independent of its neighbours' content), which
+    says nothing about slot isolation."""
+    cfg = dataclasses.replace(CFG, dtype="float32")
+    api = get_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    pa, pb, pc = _prompt(rng, 12), _prompt(rng, 20), _prompt(rng, 20)
     _, sa = api.prefill(params, {"tokens": jnp.asarray(pa[None])}, MAX_SEQ)
-    _, sb = api.prefill(params, {"tokens": jnp.asarray(pb[None])}, MAX_SEQ)
-    pool = api.init_state(3, MAX_SEQ)
-    pool = api.write_into_slot(pool, sa, 1)
-    pool = api.write_into_slot(pool, sb, 2)
+    pools = []
+    for other in (pb, pc):
+        _, so = api.prefill(params, {"tokens": jnp.asarray(other[None])},
+                            MAX_SEQ)
+        pool = api.init_state(3, MAX_SEQ)
+        pool = api.write_into_slot(pool, sa, 1)
+        pools.append(api.write_into_slot(pool, so, 2))
     active = jnp.asarray([False, True, True])
     solo = sa
     toks = [7, 11, 2]
     for t in toks:
-        logits_p, pool = api.decode_step(
-            params, pool, jnp.asarray([0, t, 9], jnp.int32), None, active=active)
+        logits = []
+        for i, pool in enumerate(pools):
+            logits_p, pools[i] = api.decode_step(
+                params, pool, jnp.asarray([0, t, 9], jnp.int32), None,
+                active=active)
+            logits.append(np.asarray(logits_p[1]))
         logits_s, solo = api.decode_step(params, solo,
                                          jnp.asarray([t], jnp.int32))
-        np.testing.assert_allclose(np.asarray(logits_p[1]),
-                                   np.asarray(logits_s[0]),
+        np.testing.assert_array_equal(logits[0], logits[1])
+        np.testing.assert_allclose(logits[0], np.asarray(logits_s[0]),
                                    rtol=2e-3, atol=2e-4)
 
 
