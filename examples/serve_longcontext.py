@@ -119,7 +119,7 @@ def main() -> None:
             max_new_tokens=args.new_tokens))
     stats = engine.run()
     s = stats.summary()
-    print(f"completed {s['completed']} requests | prefill {s['prefill_s']}s "
+    print(f"completed {s['completed']} requests | admission {s['admit_s']}s "
           f"decode {s['decode_s']}s over {s['ticks']} ticks "
           f"({s['decode_calls']} fused decode calls, "
           f"{s['decode_ms_per_tick']} ms/tick, "
@@ -134,8 +134,8 @@ def main() -> None:
         print(f"prefix sharing: {s['shared_blocks']} blocks shared across "
               f"{s['prefix_hits']} hits, {s['cow_copies']} CoW copies, "
               f"{s['memory_saved_tokens']} tokens of HBM saved")
-    print("decode/(prefill+decode) time share: "
-          f"{s['decode_s']/(s['prefill_s']+s['decode_s']):.1%} "
+    print("decode/(admission+decode) time share: "
+          f"{s['decode_s']/(s['admit_s']+s['decode_s']):.1%} "
           "(the paper's Fig.1 regime: decode dominates long-context serving)")
 
 

@@ -170,6 +170,10 @@ def salca_decode_attention_paged(q: jax.Array, pool: PagedSalcaCache,
     per-block histograms), and the page-table clamping rules, so the
     selection — and hence the attended token set — is bit-identical between
     them; outputs differ only by float summation order.
+
+    Each stage runs under its own `jax.named_scope` — ``salca.score``,
+    ``salca.select``, then ``salca.plan`` and ``salca.attend`` — so a device
+    trace attributes every op of the tick to the stage that issued it.
     """
     from repro.flags import PERF
     if fused is None:
@@ -177,26 +181,30 @@ def salca_decode_attention_paged(q: jax.Array, pool: PagedSalcaCache,
     h = q.shape[1]
     kv = pool.num_kv_heads
     groups = h // kv
-    q_feat = query_heavy_features(q, pool.heavy_idx, groups)
-    if fused:
-        scores = estimate_relevance_paged(q_feat, pool, groups, impl=impl,
-                                          interpret=interpret)
-    else:
-        fw, fs, fz = paged_logical_features(pool)
-        scores = estimate_relevance(q_feat, fw, fs, fz, groups)
+    with jax.named_scope("salca.score"):
+        q_feat = query_heavy_features(q, pool.heavy_idx, groups)
+        if fused:
+            scores = estimate_relevance_paged(q_feat, pool, groups, impl=impl,
+                                              interpret=interpret)
+        else:
+            fw, fs, fz = paged_logical_features(pool)
+            scores = estimate_relevance(q_feat, fw, fs, fz, groups)
     # mapped_valid_mask: identical to valid_mask unless the engine demoted a
     # cold block to host memory (page_table -1 below the cursor) — a spilled
     # block must be unselectable, not garbage-read, until promoted back.
-    sel = select_sparse_pattern_blocked(scores, params,
-                                        pool.mapped_valid_mask()[:, None, :],
-                                        pool.block_size)
+    with jax.named_scope("salca.select"):
+        sel = select_sparse_pattern_blocked(scores, params,
+                                            pool.mapped_valid_mask()[:, None, :],
+                                            pool.block_size)
     if fused:
+        # Names its own stages: salca.plan and salca.attend.
         from repro.kernels.flash_decode.ops import sparse_flash_decode_paged
         out = sparse_flash_decode_paged(q, pool, sel, impl=impl,
                                         interpret=interpret)
     else:
-        kc, ks, vc, vs = gather_selected_paged(pool, sel)
-        out = exact_sparse_attention(q, kc, ks, vc, vs, sel.mask)
+        with jax.named_scope("salca.attend"):
+            kc, ks, vc, vs = gather_selected_paged(pool, sel)
+            out = exact_sparse_attention(q, kc, ks, vc, vs, sel.mask)
     if return_selection:
         return out, sel
     return out

@@ -440,110 +440,115 @@ def _sp_salca_decode_paged_fused(q: jax.Array, pool: PagedSalcaCache,
     groups = h // kv
     bs, mb = pool.block_size, pool.max_blocks
     n = pool.max_seq
-    block_range, owned_blk, local_pt = _shard_pool_view(pool, axis_name)
-    pos_blk = jnp.arange(n, dtype=jnp.int32).reshape(mb, bs)
-    stored = pos_blk[None] < pool.length[:, None, None]            # (S,MB,BS)
-    blk_valid = owned_blk[..., None] & stored                      # (S,MB,BS)
-    mask3 = blk_valid.reshape(s_, 1, n)
+    with jax.named_scope("salca.score"):
+        block_range, owned_blk, local_pt = _shard_pool_view(pool, axis_name)
+        pos_blk = jnp.arange(n, dtype=jnp.int32).reshape(mb, bs)
+        stored = pos_blk[None] < pool.length[:, None, None]            # (S,MB,BS)
+        blk_valid = owned_blk[..., None] & stored                      # (S,MB,BS)
+        mask3 = blk_valid.reshape(s_, 1, n)
 
-    # --- Kernel 1: streaming scores + raw bounds over owned blocks ------
-    q_feat = query_heavy_features(q, pool.heavy_idx, groups)
-    qg = q.reshape(s_, kv, groups, hd).astype(jnp.float32)   # phase-4 operand
-    sm, lo_l, hi_l = estimate_relevance_paged_bounds(
-        q_feat, pool, groups, blk_valid, pages=local_pt,
-        impl=impl, interpret=interpret)                          # (S,KV,L)
+        # --- Kernel 1: streaming scores + raw bounds over owned blocks ------
+        q_feat = query_heavy_features(q, pool.heavy_idx, groups)
+        qg = q.reshape(s_, kv, groups, hd).astype(jnp.float32)   # phase-4 operand
+        sm, lo_l, hi_l = estimate_relevance_paged_bounds(
+            q_feat, pool, groups, blk_valid, pages=local_pt,
+            impl=impl, interpret=interpret)                          # (S,KV,L)
 
-    # --- Collective 1a: merged binning bounds + pre-pool halo columns ---
-    lo = jax.lax.pmin(lo_l, axis_name)
-    hi = jax.lax.pmax(hi_l, axis_name)
-    blocked = sm.reshape(s_, kv, mb, bs)
-    use_pool = params.use_pool and params.pool_window > 1
-    w = params.pool_window if use_pool else 1
-    if use_pool:
-        halo = w // 2
-        # Bin ONLY each block's edge columns in XLA (O(MB·halo) work) with
-        # the merged global affine — bit-identical to slicing the full bins,
-        # which kernel 2 computes in VMEM. Each column is nonzero only on
-        # its owner, so one psum reconstructs every block's true edges.
-        edge_s = jnp.concatenate([blocked[..., -halo:],
-                                  blocked[..., :halo]], axis=-1)
-        edge_v = jnp.concatenate([blk_valid[..., -halo:],
-                                  blk_valid[..., :halo]], axis=-1)[:, None]
-        edge_bins = qz.bins_from_bounds(
-            edge_s.reshape(s_, kv, mb * 2 * halo), lo, hi,
-            edge_v.reshape(s_, 1, mb * 2 * halo)).reshape(s_, kv, mb, 2 * halo)
-        edges = jax.lax.psum(edge_bins.astype(jnp.int32), axis_name)
-        left, right = edges[..., :halo], edges[..., halo:]
-        zero = jnp.zeros(left.shape[:-2] + (1, halo), jnp.int32)
-        from_left = jnp.concatenate([zero, left[..., :-1, :]],
-                                    axis=-2).astype(jnp.uint8)
-        from_right = jnp.concatenate([right[..., 1:, :], zero],
-                                     axis=-2).astype(jnp.uint8)
-    else:
-        from_left = jnp.zeros((s_, kv, mb, 1), jnp.uint8)
-        from_right = jnp.zeros((s_, kv, mb, 1), jnp.uint8)
-    if params.sink_tokens or params.recent_tokens:
-        pos = jnp.arange(n)
-        forced = jnp.zeros((n,), bool)
-        if params.sink_tokens:
-            forced |= pos < params.sink_tokens
-        if params.recent_tokens:
-            length = jnp.sum(pool.valid_mask().astype(jnp.int32), axis=-1,
-                             keepdims=True)
-            forced = forced[None, :] | (pos[None, :]
-                                        >= (length - params.recent_tokens))
-        force = jnp.broadcast_to(forced, (s_, n)).reshape(s_, mb, bs)
-    else:
-        force = jnp.zeros((s_, mb, bs), jnp.bool_)
+    with jax.named_scope("salca.select"):
+        # --- Collective 1a: merged binning bounds + pre-pool halo columns ---
+        lo = jax.lax.pmin(lo_l, axis_name)
+        hi = jax.lax.pmax(hi_l, axis_name)
+        blocked = sm.reshape(s_, kv, mb, bs)
+        use_pool = params.use_pool and params.pool_window > 1
+        w = params.pool_window if use_pool else 1
+        if use_pool:
+            halo = w // 2
+            # Bin ONLY each block's edge columns in XLA (O(MB·halo) work) with
+            # the merged global affine — bit-identical to slicing the full bins,
+            # which kernel 2 computes in VMEM. Each column is nonzero only on
+            # its owner, so one psum reconstructs every block's true edges.
+            edge_s = jnp.concatenate([blocked[..., -halo:],
+                                      blocked[..., :halo]], axis=-1)
+            edge_v = jnp.concatenate([blk_valid[..., -halo:],
+                                      blk_valid[..., :halo]], axis=-1)[:, None]
+            edge_bins = qz.bins_from_bounds(
+                edge_s.reshape(s_, kv, mb * 2 * halo), lo, hi,
+                edge_v.reshape(s_, 1, mb * 2 * halo)).reshape(s_, kv, mb, 2 * halo)
+            edges = jax.lax.psum(edge_bins.astype(jnp.int32), axis_name)
+            left, right = edges[..., :halo], edges[..., halo:]
+            zero = jnp.zeros(left.shape[:-2] + (1, halo), jnp.int32)
+            from_left = jnp.concatenate([zero, left[..., :-1, :]],
+                                        axis=-2).astype(jnp.uint8)
+            from_right = jnp.concatenate([right[..., 1:, :], zero],
+                                         axis=-2).astype(jnp.uint8)
+        else:
+            from_left = jnp.zeros((s_, kv, mb, 1), jnp.uint8)
+            from_right = jnp.zeros((s_, kv, mb, 1), jnp.uint8)
+        if params.sink_tokens or params.recent_tokens:
+            pos = jnp.arange(n)
+            forced = jnp.zeros((n,), bool)
+            if params.sink_tokens:
+                forced |= pos < params.sink_tokens
+            if params.recent_tokens:
+                length = jnp.sum(pool.valid_mask().astype(jnp.int32), axis=-1,
+                                 keepdims=True)
+                forced = forced[None, :] | (pos[None, :]
+                                            >= (length - params.recent_tokens))
+            force = jnp.broadcast_to(forced, (s_, n)).reshape(s_, mb, bs)
+        else:
+            force = jnp.zeros((s_, mb, bs), jnp.bool_)
 
-    # --- Kernel 2: fused bin/pool/hist, scores consumed in place --------
-    pooled4, hist_l = paged_fused_select(
-        blocked, lo, hi, from_left, from_right, blk_valid, force,
-        window=w, impl=impl, interpret=interpret)
-    pooled = pooled4.reshape(s_, kv, n)
+        # --- Kernel 2: fused bin/pool/hist, scores consumed in place --------
+        pooled4, hist_l = paged_fused_select(
+            blocked, lo, hi, from_left, from_right, blk_valid, force,
+            window=w, impl=impl, interpret=interpret)
+        pooled = pooled4.reshape(s_, kv, n)
 
-    # --- Collective 1b: histogram psum → threshold; global rank ---------
-    # Identical XLA to the gather island from here to the Selection.
-    hist = jax.lax.psum(hist_l, axis_name)
-    t = ht.locate_threshold(hist, params.k)                          # (S, KV)
-    keep = pooled >= t[..., None].astype(pooled.dtype)
-    kb = keep.reshape(s_, kv, mb, bs)
-    blk_counts = jax.lax.psum(jnp.sum(kb.astype(jnp.int32), axis=-1),
-                              axis_name)                             # (S,KV,MB)
-    base = jnp.cumsum(blk_counts, axis=-1) - blk_counts              # exclusive
-    within = jnp.cumsum(kb.astype(jnp.int32), axis=-1) - 1
-    grank = (base[..., None] + within).reshape(s_, kv, n)
-    keep = keep & (grank < params.k_cap)
-    indices, mask, count = ht.compact_indices(keep, shard_cap)
-    sel = ht.Selection(indices, mask, count, t)
+        # --- Collective 1b: histogram psum → threshold; global rank ---------
+        # Identical XLA to the gather island from here to the Selection.
+        hist = jax.lax.psum(hist_l, axis_name)
+        t = ht.locate_threshold(hist, params.k)                          # (S, KV)
+        keep = pooled >= t[..., None].astype(pooled.dtype)
+        kb = keep.reshape(s_, kv, mb, bs)
+        blk_counts = jax.lax.psum(jnp.sum(kb.astype(jnp.int32), axis=-1),
+                                  axis_name)                             # (S,KV,MB)
+        base = jnp.cumsum(blk_counts, axis=-1) - blk_counts              # exclusive
+        within = jnp.cumsum(kb.astype(jnp.int32), axis=-1) - 1
+        grank = (base[..., None] + within).reshape(s_, kv, n)
+        keep = keep & (grank < params.k_cap)
+        indices, mask, count = ht.compact_indices(keep, shard_cap)
+        sel = ht.Selection(indices, mask, count, t)
 
     # --- Kernel 3 + collective 2: sharded exact attention ---------------
     phase4 = impl
     if phase4 is None:
         phase4 = "pallas" if paged_impl_default() == "pallas" else "gather"
-    if phase4 == "gather":
-        # Row-gather + einsum partials with the gather island's merge
-        # (pmax BEFORE exp) — bitwise that path's phase 4, making the
-        # platform-default fused tick fully bitwise vs the gather island.
-        kc, ks, vc, vs = gather_selected_paged(pool, sel, block_range)
-        s = jnp.einsum("bkgd,bkcd->bkgc", qg, kc.astype(jnp.float32))
-        s = s * ks[:, :, None, :] / jnp.sqrt(hd).astype(jnp.float32)
-        s = jnp.where(mask[:, :, None, :], s, NEG_INF)
-        m_g = jax.lax.pmax(jnp.max(s, axis=-1), axis_name)
-        p = jnp.exp(s - m_g[..., None])
-        p = jnp.where(mask[:, :, None, :], p, 0.0)
-        l_g = jax.lax.psum(jnp.sum(p, axis=-1), axis_name)
-        v = vc.astype(jnp.float32) * vs[..., None]
-        acc_g = jax.lax.psum(jnp.einsum("bkgc,bkcd->bkgd", p, v), axis_name)
-    else:
+    if phase4 != "gather":
+        # Names its own stages: salca.plan and salca.attend.
         acc_l, m_l, l_l = sparse_flash_decode_paged_partials(
             q, pool, sel, block_range=block_range, impl=phase4,
             interpret=interpret)                                 # (S,KV,G,·)
-        m_g = jax.lax.pmax(m_l, axis_name)
-        corr = jnp.exp(m_l - m_g)
-        l_g = jax.lax.psum(l_l * corr, axis_name)
-        acc_g = jax.lax.psum(acc_l * corr[..., None], axis_name)
-    out = (acc_g / jnp.maximum(l_g, 1e-20)[..., None]).reshape(s_, h, hd)
+    with jax.named_scope("salca.attend"):
+        if phase4 == "gather":
+            # Row-gather + einsum partials with the gather island's merge
+            # (pmax BEFORE exp) — bitwise that path's phase 4, making the
+            # platform-default fused tick fully bitwise vs the gather island.
+            kc, ks, vc, vs = gather_selected_paged(pool, sel, block_range)
+            s = jnp.einsum("bkgd,bkcd->bkgc", qg, kc.astype(jnp.float32))
+            s = s * ks[:, :, None, :] / jnp.sqrt(hd).astype(jnp.float32)
+            s = jnp.where(mask[:, :, None, :], s, NEG_INF)
+            m_g = jax.lax.pmax(jnp.max(s, axis=-1), axis_name)
+            p = jnp.exp(s - m_g[..., None])
+            p = jnp.where(mask[:, :, None, :], p, 0.0)
+            l_g = jax.lax.psum(jnp.sum(p, axis=-1), axis_name)
+            v = vc.astype(jnp.float32) * vs[..., None]
+            acc_g = jax.lax.psum(jnp.einsum("bkgc,bkcd->bkgd", p, v), axis_name)
+        else:
+            m_g = jax.lax.pmax(m_l, axis_name)
+            corr = jnp.exp(m_l - m_g)
+            l_g = jax.lax.psum(l_l * corr, axis_name)
+            acc_g = jax.lax.psum(acc_l * corr[..., None], axis_name)
+        out = (acc_g / jnp.maximum(l_g, 1e-20)[..., None]).reshape(s_, h, hd)
     if return_selection:
         return out, sel
     return out

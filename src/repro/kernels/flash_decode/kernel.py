@@ -118,6 +118,7 @@ def sparse_flash_decode_pallas(q: jax.Array, k_codes: jax.Array, k_scale: jax.Ar
         out_shape=jax.ShapeDtypeStruct((bh, g, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32)] * 2
         + [pltpu.VMEM((g, hd), jnp.float32)],
+        name="sparse_flash_decode_pallas",
         interpret=interpret,
     )(q, k_codes, as_row(k_scale, jnp.float32), v_codes,
       as_row(v_scale, jnp.float32), as_row(mask, jnp.int32))
@@ -262,6 +263,8 @@ def _paged_call(q, k_codes, k_scale, v_codes, v_scale, pblk, counts,
                           partials=partials),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=("sparse_flash_decode_paged_partials_pallas" if partials
+              else "sparse_flash_decode_paged_pallas"),
         interpret=interpret,
     )(pblk, counts, *q_parts, k_codes, k_scale.reshape(p, kv, 1, sb),
       v_codes, v_scale.reshape(p, kv, 1, sb),

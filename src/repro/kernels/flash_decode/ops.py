@@ -114,33 +114,37 @@ def sparse_flash_decode_paged(q: jax.Array, pool, sel, *, impl: str | None = Non
     if impl == "gather":
         from repro.core.attention import exact_sparse_attention
         from repro.core.cache import gather_selected_paged
-        kc, ks, vc, vs = gather_selected_paged(pool, sel)      # (S, KV, C, ·)
-        if on_tpu:
-            # Gathered rows through the flat flash-decode kernel (the PR 2/3
-            # TPU fallback path).
-            c = kc.shape[2]
-            out = sparse_flash_decode(
-                q.reshape(s * kv, g, hd),
-                kc.reshape(s * kv, c, hd), ks.reshape(s * kv, c),
-                vc.reshape(s * kv, c, hd), vs.reshape(s * kv, c),
-                sel.mask.reshape(s * kv, c), impl="pallas", interpret=interpret)
-            return out.reshape(s, h, hd)
-        return exact_sparse_attention(q, kc, ks, vc, vs, sel.mask)
-    pblk, counts, bmask = _selected_block_plan(pool, sel)
-    qr = q.reshape(s * kv, g, hd)
-    if impl == "pallas":
-        out = sparse_flash_decode_paged_pallas(
-            qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
-            pblk, counts, bmask, num_kv=kv, kv_dtype=pool.kv_pool_dtype,
-            interpret=interpret)
-    elif impl == "ref":
-        out = sparse_flash_decode_paged_ref(
-            qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
-            pblk, bmask, kv, kv_dtype=pool.kv_pool_dtype)
-    else:
+        with jax.named_scope("salca.attend"):
+            kc, ks, vc, vs = gather_selected_paged(pool, sel)  # (S, KV, C, ·)
+            if on_tpu:
+                # Gathered rows through the flat flash-decode kernel (the PR
+                # 2/3 TPU fallback path).
+                c = kc.shape[2]
+                out = sparse_flash_decode(
+                    q.reshape(s * kv, g, hd),
+                    kc.reshape(s * kv, c, hd), ks.reshape(s * kv, c),
+                    vc.reshape(s * kv, c, hd), vs.reshape(s * kv, c),
+                    sel.mask.reshape(s * kv, c), impl="pallas",
+                    interpret=interpret)
+                return out.reshape(s, h, hd)
+            return exact_sparse_attention(q, kc, ks, vc, vs, sel.mask)
+    if impl not in ("pallas", "ref"):
         raise ValueError(f"unknown impl {impl!r} "
                          "(expected 'pallas', 'ref' or 'gather')")
-    return out.reshape(s, h, hd)
+    with jax.named_scope("salca.plan"):
+        pblk, counts, bmask = _selected_block_plan(pool, sel)
+    with jax.named_scope("salca.attend"):
+        qr = q.reshape(s * kv, g, hd)
+        if impl == "pallas":
+            out = sparse_flash_decode_paged_pallas(
+                qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
+                pblk, counts, bmask, num_kv=kv, kv_dtype=pool.kv_pool_dtype,
+                interpret=interpret)
+        else:
+            out = sparse_flash_decode_paged_ref(
+                qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
+                pblk, bmask, kv, kv_dtype=pool.kv_pool_dtype)
+        return out.reshape(s, h, hd)
 
 
 def sparse_flash_decode_paged_partials(q: jax.Array, pool, sel, *,
@@ -161,18 +165,20 @@ def sparse_flash_decode_paged_partials(q: jax.Array, pool, sel, *,
     g = h // kv
     if impl is None:
         impl = paged_impl_default()
-    pblk, counts, bmask = _selected_block_plan(pool, sel, block_range)
-    qr = q.reshape(s * kv, g, hd)
-    if impl == "pallas":
-        acc, m, l = sparse_flash_decode_paged_partials_pallas(
-            qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
-            pblk, counts, bmask, num_kv=kv, kv_dtype=pool.kv_pool_dtype,
-            interpret=interpret)
-    elif impl == "ref":
-        acc, m, l = sparse_flash_decode_paged_partials_ref(
-            qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
-            pblk, bmask, kv, kv_dtype=pool.kv_pool_dtype)
-    else:
+    if impl not in ("pallas", "ref"):
         raise ValueError(f"unknown impl {impl!r} (expected 'pallas' or 'ref')")
-    return (acc.reshape(s, kv, g, hd), m.reshape(s, kv, g),
-            l.reshape(s, kv, g))
+    with jax.named_scope("salca.plan"):
+        pblk, counts, bmask = _selected_block_plan(pool, sel, block_range)
+    with jax.named_scope("salca.attend"):
+        qr = q.reshape(s * kv, g, hd)
+        if impl == "pallas":
+            acc, m, l = sparse_flash_decode_paged_partials_pallas(
+                qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
+                pblk, counts, bmask, num_kv=kv, kv_dtype=pool.kv_pool_dtype,
+                interpret=interpret)
+        else:
+            acc, m, l = sparse_flash_decode_paged_partials_ref(
+                qr, pool.k_codes, pool.k_scale, pool.v_codes, pool.v_scale,
+                pblk, bmask, kv, kv_dtype=pool.kv_pool_dtype)
+        return (acc.reshape(s, kv, g, hd), m.reshape(s, kv, g),
+                l.reshape(s, kv, g))

@@ -133,5 +133,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
+        name="flash_attention_pallas",
         interpret=interpret,
     )(q, k, v)

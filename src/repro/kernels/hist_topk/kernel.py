@@ -80,6 +80,7 @@ def hist_threshold_pallas(bins: jax.Array, k: jax.Array,
             jax.ShapeDtypeStruct((bh,), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((NUM_BINS,), jnp.int32)],
+        name="hist_threshold_pallas",
         interpret=interpret,
     )(bins, k.astype(jnp.int32))
     return hist, thr

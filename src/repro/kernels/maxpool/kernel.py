@@ -84,5 +84,6 @@ def maxpool_pallas(bins: jax.Array, window: int, *, block_n: int = DEFAULT_BLOCK
         ],
         out_specs=pl.BlockSpec((1, bn), centre),
         out_shape=jax.ShapeDtypeStruct((bh, n), jnp.uint8),
+        name="maxpool_pallas",
         interpret=interpret,
     )(bins, bins, bins)

@@ -76,6 +76,7 @@ def score_estimate_pallas(q_codes: jax.Array, q_scale: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, bn), lambda b, j: (b, j)),
         out_shape=jax.ShapeDtypeStruct((bh, n), jnp.float32),
+        name="score_estimate_pallas",
         interpret=interpret,
     )(q_codes, q_scale, words, feat_scale, feat_zero)
 
@@ -230,6 +231,7 @@ def paged_score_bounds_pallas(q_codes: jax.Array, q_scale: jax.Array,
             jax.ShapeDtypeStruct((s, kv, 1), jnp.float32),
             jax.ShapeDtypeStruct((s, kv, 1), jnp.float32),
         ],
+        name="paged_score_bounds_pallas",
         interpret=interpret,
     )(pages, *ops, blk_valid.astype(jnp.int32).reshape(s, mb, 1, bs))
     return _logical_scores(scores), lo[..., 0], hi[..., 0]
@@ -269,6 +271,7 @@ def paged_score_estimate_pallas(q_codes: jax.Array, q_scale: jax.Array,
         functools.partial(_paged_kernel, bf16=bf16),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, mb, kv, bs), jnp.float32),
+        name="paged_score_estimate_pallas",
         interpret=interpret,
     )(pages, *ops)
     return _logical_scores(scores)

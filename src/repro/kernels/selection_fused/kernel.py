@@ -155,6 +155,7 @@ def fused_bin_pool_threshold_pallas(scores: jax.Array, lo: jax.Array,
             jax.ShapeDtypeStruct((bh, NUM_BINS, 1), jnp.int32),
             jax.ShapeDtypeStruct((bh, 1, 1), jnp.int32),
         ],
+        name="fused_bin_pool_threshold_pallas",
         interpret=interpret,
     )(k.astype(jnp.int32), lengths.astype(jnp.int32), s3, s3, s3,
       lo.astype(jnp.float32).reshape(bh, 1, 1),
@@ -262,6 +263,7 @@ def paged_fused_select_pallas(scores: jax.Array, lo: jax.Array, hi: jax.Array,
             jax.ShapeDtypeStruct((s, kv, NUM_BINS, 1), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((NUM_BINS, 1), jnp.int32)],
+        name="paged_fused_select_pallas",
         interpret=interpret,
     )(lanes(scores, jnp.float32), lo.astype(jnp.float32).reshape(s, kv, 1, 1),
       hi.astype(jnp.float32).reshape(s, kv, 1, 1), halos.reshape(s, kv, mb, 1, w),

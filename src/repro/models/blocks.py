@@ -316,7 +316,8 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
     """
     b, d = x.shape
     h, hd = cfg.num_heads, cfg.resolved_head_dim
-    q, k, v = attn_decode_qkv(params, x, cfg, pos)
+    with jax.named_scope("dense"):
+        q, k, v = attn_decode_qkv(params, x, cfg, pos)
 
     paged = isinstance(cache, PagedSalcaCache)
     ring = (not paged) and window > 0 and cache.max_seq <= window
@@ -357,9 +358,10 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
         sa = ctx.axis
 
         def paged_island(q_, k_, v_, wp_, vl_, pos_, pool_):
-            pool_ = append_token_paged(
-                pool_._replace(length=wp_), k_, v_,
-                block_range=local_block_range(pool_, sa))
+            with jax.named_scope("kv.write"):
+                pool_ = append_token_paged(
+                    pool_._replace(length=wp_), k_, v_,
+                    block_range=local_block_range(pool_, sa))
             pool_ = pool_._replace(length=vl_)
             if use_salca:
                 o_ = sp_salca_decode_paged(q_, pool_, salca, sa)
@@ -380,7 +382,8 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
         # Paged block pool: the write cursor resolves through the slot's page
         # table (unmapped / out-of-capacity writes are dropped, no silent
         # clip — the engine grows or overflow-finishes first).
-        cache = append_token_paged(cache._replace(length=write_pos), k, v)
+        with jax.named_scope("kv.write"):
+            cache = append_token_paged(cache._replace(length=write_pos), k, v)
         cache = cache._replace(length=valid_len)
         if use_salca:
             # Fused vs gather data path is chosen inside (PERF.paged_fused_
@@ -392,7 +395,8 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
             # selected tokens per logical block (O(S·KV·C) scatter-add; the
             # engine diffs snapshots host-side to find cold blocks).
             from repro.core.cache import record_selection
-            cache = record_selection(cache, sel.indices, sel.mask)
+            with jax.named_scope("salca.history"):
+                cache = record_selection(cache, sel.indices, sel.mask)
         else:
             valid = cache.valid_mask()
             if window > 0:
@@ -401,7 +405,8 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
             o = dense_decode_from_paged(q, cache, valid)
     elif ctx.axis is None:
         from repro.core.cache import append_token
-        cache = append_token(cache._replace(length=write_pos), k, v)
+        with jax.named_scope("kv.write"):
+            cache = append_token(cache._replace(length=write_pos), k, v)
         cache = cache._replace(length=valid_len)
         if use_salca:
             o = salca_decode_attention(q, cache, salca)
@@ -441,8 +446,9 @@ def _attn_decode(params: dict, x: jax.Array, cache: SalcaCache, cfg: ModelConfig
             out_specs=(rep3, cache_pspec(ctx)),
             check_vma=False,
         )(q, k, v, write_pos, valid_len, cache)
-    o = o.astype(x.dtype).reshape(b, h * hd)
-    return o @ params["wo"], cache
+    with jax.named_scope("dense"):
+        o = o.astype(x.dtype).reshape(b, h * hd)
+        return o @ params["wo"], cache
 
 
 def merge_masked_state(new_state, old_state, active: jax.Array):
@@ -468,13 +474,15 @@ def block_decode(params: dict, kind: str, x: jax.Array, state, cfg: ModelConfig,
     if kind in ("A", "L"):
         window = cfg.local_window if kind == "L" else 0
         use_salca = cfg.salca and kind == "A"
-        h, state = _attn_decode(params["attn"],
-                                rmsnorm(params["ln1"], x, cfg.norm_eps),
-                                state, cfg, pos, window, use_salca, ctx, salca,
-                                active)
-        x = x + h
-        f, _ = ffn_apply(params["ffn"], rmsnorm(params["ln2"], x, cfg.norm_eps), cfg)
-        return x + f, state
+        with jax.named_scope("dense"):
+            xn = rmsnorm(params["ln1"], x, cfg.norm_eps)
+        h, state = _attn_decode(params["attn"], xn, state, cfg, pos, window,
+                                use_salca, ctx, salca, active)
+        with jax.named_scope("dense"):
+            x = x + h
+            f, _ = ffn_apply(params["ffn"],
+                             rmsnorm(params["ln2"], x, cfg.norm_eps), cfg)
+            return x + f, state
     if kind == "S":
         h, new = ssm.ssd_decode(params["ssd"],
                                 rmsnorm(params["ln1"], x, cfg.norm_eps), state, cfg)

@@ -225,13 +225,18 @@ def lm_prefill_chunk(params: dict, cfg: ModelConfig, pool: LMState,
     next-token distribution on the final chunk and None otherwise. On the
     final chunk the pool's `pos[slot]` is set so decode resumes exactly
     where `lm_prefill` + `lm_write_into_slot` would have left it.
+
+    Its ops run under the ``prefill.chunk`` scope, opened inside the layer
+    scan's body and around it (never around the scan: a trace would then
+    count the loop and its body twice).
     """
     from repro.core.cache import prefill_chunk_into_pages
     pattern, n_periods, tail = pattern_layout(cfg)
     reason = lm_prefill_chunk_unsupported(cfg)
     if reason is not None:
         raise ValueError(f"chunked prefill unsupported: {reason}")
-    h = embed_inputs(params, cfg, tokens)
+    with jax.named_scope("prefill.chunk"):
+        h = embed_inputs(params, cfg, tokens)
     t0 = cursor.t0
     sp = B.salca_params_for(cfg, max_seq)
 
@@ -240,37 +245,39 @@ def lm_prefill_chunk(params: dict, cfg: ModelConfig, pool: LMState,
         def body(h, xs):
             pps, kvs, psts = xs
             new_kvs, new_psts = [], []
-            for i, _ in enumerate(pattern):
-                kb, vb = kvs[i]
-                h, kb, vb, k, v = B.block_prefill_chunk(pps[i], h, kb, vb,
-                                                        t0, cfg)
-                heavy = B.static_heavy_idx(pps[i]["attn"], cfg, sp, 1)
-                new_psts.append(prefill_chunk_into_pages(
-                    psts[i], k, v, heavy, slot, pages, t0, n_shared))
-                new_kvs.append((kb, vb))
+            with jax.named_scope("prefill.chunk"):
+                for i, _ in enumerate(pattern):
+                    kb, vb = kvs[i]
+                    h, kb, vb, k, v = B.block_prefill_chunk(pps[i], h, kb, vb,
+                                                            t0, cfg)
+                    heavy = B.static_heavy_idx(pps[i]["attn"], cfg, sp, 1)
+                    new_psts.append(prefill_chunk_into_pages(
+                        psts[i], k, v, heavy, slot, pages, t0, n_shared))
+                    new_kvs.append((kb, vb))
             return h, (tuple(new_kvs), tuple(new_psts))
 
         h, (period_kv, period_states) = jax.lax.scan(
             body, h, (params["periods"], cursor.period_kv, pool.period_states))
 
     tail_kv, tail_states = [], list(pool.tail_states)
-    for i, _ in enumerate(tail):
-        kb, vb = cursor.tail_kv[i]
-        h, kb, vb, k, v = B.block_prefill_chunk(params["tail"][i], h, kb, vb,
-                                                t0, cfg)
-        heavy = B.static_heavy_idx(params["tail"][i]["attn"], cfg, sp, 1)
-        tail_states[i] = prefill_chunk_into_pages(
-            tail_states[i], k, v, heavy, slot, pages, t0, n_shared)
-        tail_kv.append((kb, vb))
+    with jax.named_scope("prefill.chunk"):
+        for i, _ in enumerate(tail):
+            kb, vb = cursor.tail_kv[i]
+            h, kb, vb, k, v = B.block_prefill_chunk(params["tail"][i], h, kb,
+                                                    vb, t0, cfg)
+            heavy = B.static_heavy_idx(params["tail"][i]["attn"], cfg, sp, 1)
+            tail_states[i] = prefill_chunk_into_pages(
+                tail_states[i], k, v, heavy, slot, pages, t0, n_shared)
+            tail_kv.append((kb, vb))
 
-    c = tokens.shape[1]
-    if final:
-        hn = rmsnorm(params["ln_f"], h, cfg.norm_eps)
-        logits = vocab_mask_logits(lm_logits(params["embed"], hn[:, -1], cfg),
-                                   cfg)
-        pos = pool.pos.at[slot].set(t0 + c)
-    else:
-        logits, pos = None, pool.pos
+        c = tokens.shape[1]
+        if final:
+            hn = rmsnorm(params["ln_f"], h, cfg.norm_eps)
+            logits = vocab_mask_logits(
+                lm_logits(params["embed"], hn[:, -1], cfg), cfg)
+            pos = pool.pos.at[slot].set(t0 + c)
+        else:
+            logits, pos = None, pool.pos
     new_pool = LMState(period_states, tuple(tail_states), pos)
     return logits, new_pool, PrefillCursor(period_kv, tuple(tail_kv), t0 + c)
 
@@ -325,19 +332,21 @@ def lm_adopt_pages(params: dict, cfg: ModelConfig, pool: LMState, slot,
     if max_seq is None:
         raise ValueError("adopt_pages requires a paged pool state")
     sp = B.salca_params_for(cfg, max_seq)
-    ln = jnp.asarray(length, jnp.int32)
-    periods = tuple(
-        jax.vmap(lambda st, p: adopt_pages(
-            st, slot, pages, ln, B.static_heavy_idx(p["attn"], cfg, sp, 1)
-        ))(pp, params["periods"][i])
-        if isinstance(pp, B.PagedSalcaCache) else pp
-        for i, pp in enumerate(pool.period_states))
-    tails = tuple(
-        adopt_pages(st, slot, pages, ln,
-                    B.static_heavy_idx(params["tail"][i]["attn"], cfg, sp, 1))
-        if isinstance(st, B.PagedSalcaCache) else st
-        for i, st in enumerate(pool.tail_states))
-    return LMState(periods, tails, pool.pos.at[slot].set(ln))
+    with jax.named_scope("prefill.adopt"):
+        ln = jnp.asarray(length, jnp.int32)
+        periods = tuple(
+            jax.vmap(lambda st, p: adopt_pages(
+                st, slot, pages, ln, B.static_heavy_idx(p["attn"], cfg, sp, 1)
+            ))(pp, params["periods"][i])
+            if isinstance(pp, B.PagedSalcaCache) else pp
+            for i, pp in enumerate(pool.period_states))
+        tails = tuple(
+            adopt_pages(st, slot, pages, ln,
+                        B.static_heavy_idx(params["tail"][i]["attn"], cfg, sp,
+                                           1))
+            if isinstance(st, B.PagedSalcaCache) else st
+            for i, st in enumerate(pool.tail_states))
+        return LMState(periods, tails, pool.pos.at[slot].set(ln))
 
 
 def lm_calibrate_static_heavy(params: dict, cfg: ModelConfig,
@@ -599,7 +608,8 @@ def lm_decode_step(params: dict, cfg: ModelConfig, state: LMState,
     """
     pattern, n_periods, tail = pattern_layout(cfg)
     ctx = ctx or B.DecodeCtx()
-    h = embed_tokens(params["embed"], token).astype(cdtype(cfg))
+    with jax.named_scope("dense"):
+        h = embed_tokens(params["embed"], token).astype(cdtype(cfg))
     pos = state.pos
 
     # max_seq for salca params: derive from any attention cache in the state.
@@ -633,7 +643,8 @@ def lm_decode_step(params: dict, cfg: ModelConfig, state: LMState,
         h, st = B.block_decode(params["tail"][i], kind, h, state.tail_states[i],
                                cfg, pos, ctx, salca, active)
         new_tail.append(st)
-    h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
-    logits = vocab_mask_logits(lm_logits(params["embed"], h, cfg), cfg)
+    with jax.named_scope("dense"):
+        h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
+        logits = vocab_mask_logits(lm_logits(params["embed"], h, cfg), cfg)
     new_pos = pos + 1 if active is None else pos + active.astype(jnp.int32)
     return logits, LMState(new_period_states, tuple(new_tail), new_pos)

@@ -13,7 +13,7 @@ import json
 import logging
 import os
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 log = logging.getLogger("repro.monitor")
 
@@ -29,9 +29,11 @@ class StepMonitor:
     slow_streak: int = 0
     total_steps: int = 0
     flagged_steps: int = 0
-    history: list = field(default_factory=list)
 
-    def record(self, step: int, seconds: float, loss: float | None = None) -> dict:
+    def record(self, step: int, seconds: float, loss: float | None = None,
+               phases: dict[str, float] | None = None) -> dict:
+        """``phases`` (name → seconds, summing to ``seconds``) lets the
+        warning for a flagged step name where its time went."""
         self.total_steps += 1
         flagged = False
         if self.ewma is None:
@@ -41,15 +43,20 @@ class StepMonitor:
                 flagged = True
                 self.flagged_steps += 1
                 self.slow_streak += 1
-                log.warning("straggler step %d: %.3fs vs EWMA %.3fs",
-                            step, seconds, self.ewma)
+                if phases:
+                    slowest = max(phases, key=phases.get)
+                    log.warning("straggler step %d: %.3fs vs EWMA %.3fs; "
+                                "slowest phase %s %.3fs", step, seconds,
+                                self.ewma, slowest, phases[slowest])
+                else:
+                    log.warning("straggler step %d: %.3fs vs EWMA %.3fs",
+                                step, seconds, self.ewma)
             else:
                 self.slow_streak = 0
             self.ewma = (1 - self.ewma_alpha) * self.ewma + self.ewma_alpha * seconds
         alarm = self.slow_streak >= self.alarm_after
         rec = {"step": step, "seconds": seconds, "ewma": self.ewma,
                "flagged": flagged, "alarm": alarm, "loss": loss}
-        self.history.append(rec)
         if self.heartbeat_path:
             tmp = self.heartbeat_path + ".tmp"
             with open(tmp, "w") as f:

@@ -125,6 +125,7 @@ from repro.models import get_model
 from repro.models.blocks import DecodeCtx
 from repro.runtime.faults import FaultPlan
 from repro.runtime.monitor import NaNGuard, StepMonitor
+from repro.runtime.tracing import HostSpans
 
 # `_slot_blocks` sentinel for a logical block whose data lives in the host
 # tier (its page-table entry is -1 and its rows sit in the numpy mirror).
@@ -270,8 +271,18 @@ class ShardedBlockAllocator:
 
 @dataclass
 class ServeStats:
-    prefill_s: float = 0.0
-    decode_s: float = 0.0
+    # Host time per phase of a scheduler iteration, one `runtime.tracing`
+    # span each: admission (prefill chunks, adoptions and monolithic
+    # prefills, with any wait on the device they block on), then the
+    # tick's prep (expiry, promotion, growth), dispatch (uploads and the
+    # jitted call), wait (the blocking read of tokens) and post (per-slot
+    # bookkeeping, spill policy, audits).
+    admit_s: float = 0.0
+    tick_prep_s: float = 0.0
+    tick_dispatch_s: float = 0.0
+    tick_wait_s: float = 0.0
+    tick_post_s: float = 0.0
+    decode_s: float = 0.0      # dispatch + wait of every decoding tick
     decode_steps: int = 0      # per-slot token decodes (Σ active over ticks)
     ticks: int = 0             # scheduler iterations that decoded
     decode_calls: int = 0      # jitted decode dispatches (== ticks by design)
@@ -338,12 +349,13 @@ class ServeStats:
     audits: int = 0            # integrity audits run (audit_every)
     audit_failures: int = 0    # audits that reported violations
     straggler_ticks: int = 0   # ticks the StepMonitor EWMA flagged slow
-    tick_ewma_s: float = 0.0   # monitor's tick-time EWMA (0 = no monitor)
+    tick_ewma_s: float = 0.0   # monitor's EWMA of whole scheduler iterations
+                               # (admit through post; 0 = no monitor)
 
     def summary(self) -> dict:
         out = {
             "completed": self.completed,
-            "prefill_s": round(self.prefill_s, 4),
+            "admit_s": round(self.admit_s, 4),
             "decode_s": round(self.decode_s, 4),
             "decode_steps": self.decode_steps,
             "ticks": self.ticks,
@@ -509,6 +521,7 @@ class ServingEngine:
         self.paged = paged
         self.n_shards = 1           # pool shards (paged + mesh ctx only)
         self.stats = ServeStats()
+        self._spans = HostSpans(self.stats)
         self._rng = np.random.default_rng(seed)
         # -- fault tolerance / request lifecycle -----------------------
         if max_queue is not None and max_queue < 1:
@@ -721,11 +734,12 @@ class ServingEngine:
                 from repro.flags import perf_flags
                 with perf_flags(**{_fused_flag: self.fused_decode}):
                     logits, s2 = self.api.decode_step(p, s, tok, ctx, active=act)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # Per-slot quarantine signal: one (slots,) bool riding the
-            # existing device→host sync — a poisoned slot is detected
-            # without fetching the full logits and without an extra sync.
-            finite = jnp.isfinite(logits).all(axis=-1)
+            with jax.named_scope("dense"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # Per-slot quarantine signal: one (slots,) bool riding the
+                # existing device→host sync — a poisoned slot is detected
+                # without fetching the full logits and without an extra sync.
+                finite = jnp.isfinite(logits).all(axis=-1)
             return nxt, logits, finite, s2
 
         # One fused program per tick. jax.jit caches by shape, so the mask
@@ -1024,11 +1038,9 @@ class ServingEngine:
         the stash; `_drop_stash` clears it on requeue and admission."""
         if self._stash is not None and self._stash[0] is req:
             return self._stash[1]
-        t0 = time.time()
         logits, state1 = self._prefill(
             self.params, jnp.asarray(req.prompt[None]))
         logits_row = np.asarray(logits)[0]              # blocks until ready
-        self.stats.prefill_s += time.time() - t0
         self.stats.prefill_tokens += len(req.prompt)
         self._stash = (req, (logits_row, state1))
         return self._stash[1]
@@ -1456,11 +1468,22 @@ class ServingEngine:
         With `prefill_chunk` set, admission instead advances the chunked
         scheduler by one budgeted chunk per call (interleaved with decode
         ticks by `run`), so a long prompt can no longer head-of-line block
-        the decode stream."""
-        self._shed_expired_queue()
-        if self.prefill_chunk is not None:
-            self._advance_prefill()
-            return
+        the decode stream.
+
+        All of it runs in the ``serve.admit`` span, tagged with the id of
+        the request at the head of admission (in flight or queued)."""
+        head = (self._inflight.req if self._inflight is not None
+                else self._queue[0] if self._queue else None)
+        ids = {} if head is None else {"rid": head.rid}
+        with self._spans("serve.admit", **ids):
+            self._shed_expired_queue()
+            if self.prefill_chunk is not None:
+                self._advance_prefill()
+            else:
+                self._admit_whole()
+
+    def _admit_whole(self) -> None:
+        """Monolithic admission: prefill each admitted prompt whole."""
         while self._queue and self._free:
             req = self._queue[0]
             pages = None
@@ -1655,10 +1678,8 @@ class ServingEngine:
             self._hist_snap[slot] = 0
             self._cold_streak[slot] = 0
         self._note_block_usage()
-        t1 = time.time()
         self._state = self._adopt(self.params, self._state, jnp.int32(slot),
                                   jnp.asarray(pages), jnp.int32(plen))
-        self.stats.prefill_s += time.time() - t1
         req.shared_blocks = len(blocks)
         self.stats.shared_blocks += len(blocks) - n_cache
         self.stats.cache_hit_blocks += n_cache
@@ -1915,7 +1936,6 @@ class ServingEngine:
             self._refcount[b] += 1
             held.append(b)
         self._note_block_usage()
-        t0 = time.time()
         final = inf.consumed + c == plen
         toks = jnp.asarray(req.prompt[None, inf.consumed:inf.consumed + c])
         logits, self._state, inf.cursor = self._chunk_step(
@@ -1926,10 +1946,8 @@ class ServingEngine:
         self.stats.prefill_chunks += 1
         self.stats.prefill_tokens += c
         if not final:
-            self.stats.prefill_s += time.time() - t0
             return
         logits_row = np.asarray(logits)[0]          # blocks until ready
-        self.stats.prefill_s += time.time() - t0
         self._inflight = None
         if self.prefix_sharing:
             req.shared_blocks = inf.n_shared
@@ -2041,99 +2059,115 @@ class ServingEngine:
         return len(req.prompt) + len(req.output) - 1
 
     def _tick(self) -> None:
-        """ONE fused decode call advancing every active slot."""
-        now = time.time()
-        for slot, req in list(self._active.items()):
-            if self._active.get(slot) is req and self._expired(req, now):
-                self.stats.deadline_stops += 1
-                self._finish(slot, req, now, "deadline")
-        self._promote_resurrected()
-        self._grow_or_overflow()
-        if not self._active:
-            return
-        if self._mask.any():        # some slot may decode (not all stalled)
-            self.stats.peak_active_slots = max(self.stats.peak_active_slots,
-                                               int(self._mask.sum()))
-            self._last_decoded = set(np.flatnonzero(self._mask).tolist())
-            t0 = time.time()
-            nxt, logits, finite, self._state = self._decode(
-                self.params, self._state, jnp.asarray(self._tokens),
-                jnp.asarray(self._mask))
-            nxt_host = np.asarray(nxt)                  # blocks until ready
-            finite_host = np.array(finite)              # writable host copy
-            tick_s = time.time() - t0
-            self.stats.decode_s += tick_s
-            self.stats.decode_calls += 1
-            self.stats.ticks += 1
-            self.stats.decode_steps += int(self._mask.sum())
-            if self.monitor is not None:
-                rec = self.monitor.record(self.stats.ticks, tick_s)
-                self.stats.tick_ewma_s = float(rec["ewma"] or 0.0)
-                if rec["flagged"]:
-                    self.stats.straggler_ticks += 1
-            # decode_logits injection: poison selected slots' rows after
-            # the fact — the detection below is the same path a real
-            # non-finite matmul result takes.
-            if self._faults is not None:
-                for slot, req in list(self._active.items()):
-                    if slot not in self._stalled and self._fault(
-                            "decode_logits", rid=req.rid, slot=slot,
-                            tick=self.stats.ticks):
-                        finite_host[slot] = False
-            logits_host = None                          # fetched only if sampling
+        """ONE fused decode call advancing every active slot, in four host
+        spans (`runtime.tracing`): prep, dispatch, wait and post."""
+        with self._spans("serve.tick.prep"):
             now = time.time()
-            for slot in list(self._active):
-                req = self._active[slot]
-                if slot in self._stalled:
-                    continue        # paused this tick: nothing advanced
-                if self.paged:
-                    self._slot_pos[slot] += 1
-                if not bool(finite_host[slot]):
-                    # Per-slot quarantine: this slot's logits are NaN/Inf.
-                    # Its request ends with a truthful "error" stop; the
-                    # other slots' rows are independent and proceed
-                    # untouched — one poisoned slot never contaminates
-                    # the fused tick.
-                    if self._nan_guard.check_slot(slot, False):
-                        self.stats.errors += 1
-                        self._finish(slot, req, now, "error")
-                        continue
-                if self.greedy or req.temperature <= 0.0:
-                    tok = self._next_token(req, None,
-                                           greedy_tok=int(nxt_host[slot]))
-                else:
-                    if logits_host is None:
-                        logits_host = np.asarray(logits)
-                    tok = self._next_token(req, logits_host[slot])
-                self._tokens[slot] = tok
-                if req.stop_token is not None and tok == req.stop_token:
-                    self._finish(slot, req, now, "stop")
-                elif len(req.output) >= req.max_new_tokens:
-                    self._finish(slot, req, now, "length")
-        # Degraded-mode accounting: a tick that ran with a stalled slot or
-        # with a cold-pinned block on an active slot served degraded —
-        # available, but paused or at reduced attention quality.
-        if self._stalled or any(k[0] in self._active
-                                for k in self._pinned_cold):
-            self.stats.degraded_ticks += 1
-        # Re-arm stalled slots: the stall lasts exactly one tick, then the
-        # growth path retries (the fault may have cleared or backed off).
-        for slot in self._stalled:
-            if slot in self._active:
-                self._mask[slot] = True
-        self._stalled.clear()
-        self._spill_policy()
-        if self.audit_every and self.stats.ticks != self._audited_tick \
-                and self.stats.ticks % self.audit_every == 0:
-            self._audited_tick = self.stats.ticks
-            self.stats.audits += 1
-            rep = self.check_invariants()
-            self.last_audit = rep
-            if not rep.ok:
-                self.stats.audit_failures += 1
-                raise RuntimeError(
-                    f"integrity audit failed at tick {self.stats.ticks}: "
-                    f"{rep}")
+            for slot, req in list(self._active.items()):
+                if self._active.get(slot) is req and self._expired(req, now):
+                    self.stats.deadline_stops += 1
+                    self._finish(slot, req, now, "deadline")
+            self._promote_resurrected()
+            self._grow_or_overflow()
+            if not self._active:
+                return
+            decode = bool(self._mask.any())   # not all slots stalled
+            if decode:
+                self.stats.peak_active_slots = max(
+                    self.stats.peak_active_slots, int(self._mask.sum()))
+                self._last_decoded = set(np.flatnonzero(self._mask).tolist())
+        if decode:
+            with self._spans("serve.tick.dispatch") as dispatch:
+                nxt, logits, finite, self._state = self._decode(
+                    self.params, self._state, jnp.asarray(self._tokens),
+                    jnp.asarray(self._mask))
+            with self._spans("serve.tick.wait") as wait:
+                nxt_host = np.asarray(nxt)              # blocks until ready
+                finite_host = np.array(finite)          # writable host copy
+            self.stats.decode_s += dispatch.seconds + wait.seconds
+        with self._spans("serve.tick.post"):
+            if decode:
+                self._advance_slots(nxt_host, logits, finite_host)
+            # Degraded-mode accounting: a tick that ran with a stalled slot
+            # or with a cold-pinned block on an active slot served degraded
+            # — available, but paused or at reduced attention quality.
+            if self._stalled or any(k[0] in self._active
+                                    for k in self._pinned_cold):
+                self.stats.degraded_ticks += 1
+            # Re-arm stalled slots: the stall lasts exactly one tick, then
+            # the growth path retries (the fault may have cleared or backed
+            # off).
+            for slot in self._stalled:
+                if slot in self._active:
+                    self._mask[slot] = True
+            self._stalled.clear()
+            self._spill_policy()
+            if self.audit_every and self.stats.ticks != self._audited_tick \
+                    and self.stats.ticks % self.audit_every == 0:
+                self._audited_tick = self.stats.ticks
+                self.stats.audits += 1
+                rep = self.check_invariants()
+                self.last_audit = rep
+                if not rep.ok:
+                    self.stats.audit_failures += 1
+                    raise RuntimeError(
+                        f"integrity audit failed at tick {self.stats.ticks}: "
+                        f"{rep}")
+        if decode and self.monitor is not None:
+            # The whole scheduler iteration, admission included, so a host
+            # stall anywhere in it is flagged — and blamed on its phase.
+            phases = self._spans.phases
+            rec = self.monitor.record(self.stats.ticks, sum(phases.values()),
+                                      phases=phases)
+            self.stats.tick_ewma_s = float(rec["ewma"] or 0.0)
+            if rec["flagged"]:
+                self.stats.straggler_ticks += 1
+
+    def _advance_slots(self, nxt_host: np.ndarray, logits,
+                       finite_host: np.ndarray) -> None:
+        """Consume one decoded tick: quarantine non-finite slots, take each
+        slot's next token and finish the requests that are done."""
+        self.stats.decode_calls += 1
+        self.stats.ticks += 1
+        self.stats.decode_steps += int(self._mask.sum())
+        # decode_logits injection: poison selected slots' rows after the
+        # fact — the detection below is the same path a real non-finite
+        # matmul result takes.
+        if self._faults is not None:
+            for slot, req in list(self._active.items()):
+                if slot not in self._stalled and self._fault(
+                        "decode_logits", rid=req.rid, slot=slot,
+                        tick=self.stats.ticks):
+                    finite_host[slot] = False
+        logits_host = None                          # fetched only if sampling
+        now = time.time()
+        for slot in list(self._active):
+            req = self._active[slot]
+            if slot in self._stalled:
+                continue        # paused this tick: nothing advanced
+            if self.paged:
+                self._slot_pos[slot] += 1
+            if not bool(finite_host[slot]):
+                # Per-slot quarantine: this slot's logits are NaN/Inf. Its
+                # request ends with a truthful "error" stop; the other
+                # slots' rows are independent and proceed untouched — one
+                # poisoned slot never contaminates the fused tick.
+                if self._nan_guard.check_slot(slot, False):
+                    self.stats.errors += 1
+                    self._finish(slot, req, now, "error")
+                    continue
+            if self.greedy or req.temperature <= 0.0:
+                tok = self._next_token(req, None,
+                                       greedy_tok=int(nxt_host[slot]))
+            else:
+                if logits_host is None:
+                    logits_host = np.asarray(logits)
+                tok = self._next_token(req, logits_host[slot])
+            self._tokens[slot] = tok
+            if req.stop_token is not None and tok == req.stop_token:
+                self._finish(slot, req, now, "stop")
+            elif len(req.output) >= req.max_new_tokens:
+                self._finish(slot, req, now, "length")
 
     # -- runtime integrity audit ---------------------------------------
 
@@ -2253,6 +2287,7 @@ class ServingEngine:
         ticks = 0
         while (self._queue or self._active or self._inflight is not None) \
                 and ticks < max_ticks:
+            self._spans.phases.clear()
             self._admit()
             if self._active:
                 self._tick()

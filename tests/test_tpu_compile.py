@@ -146,12 +146,13 @@ def test_chunked_flash_prefill_compiles(compile_tpu):
                 ((2 * KV * G, 2048, HD), jnp.bfloat16))
 
 
-def test_paged_decode_tick_compiles(one_chip, no_cache, monkeypatch):
-    """One whole jitted paged decode tick of qwen3-0.6b (2 of its 28 layers,
-    full width) with the state donated: the kernels are in the program.
+@pytest.fixture(scope="module")
+def tick_text(one_chip, no_cache):
+    """The compiled text of one whole jitted paged decode tick of qwen3-0.6b
+    (2 of its 28 layers, full width) with the state donated.
 
     The program asks the platform which kernel path to take, and here the
-    platform is the CPU, so the test steers that choice to the chip's."""
+    platform is the CPU, so the fixture steers that choice to the chip's."""
     import dataclasses
 
     import repro.kernels.common as common
@@ -159,25 +160,49 @@ def test_paged_decode_tick_compiles(one_chip, no_cache, monkeypatch):
     from repro.kernels.flash_decode import kernel as fd
     from repro.kernels.score_est import kernel as se
     from repro.models import get_model
-    for mod in (common, fd, se):
-        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    with pytest.MonkeyPatch.context() as mp:
+        for mod in (common, fd, se):
+            mp.setattr(mod, "interpret_default", lambda: False)
 
-    cfg = dataclasses.replace(get_config("qwen3-0.6b"), num_layers=2)
-    api = get_model(cfg)
-    place = lambda tree: jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        tree)
-    params = place(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
-    state = place(jax.eval_shape(
-        lambda: api.init_paged_state(S, MAX_SEQ, BS, P)))
-    tok = jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)
-    act = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+        cfg = dataclasses.replace(get_config("qwen3-0.6b"), num_layers=2)
+        api = get_model(cfg)
+        place = lambda tree: jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            tree)
+        params = place(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+        state = place(jax.eval_shape(
+            lambda: api.init_paged_state(S, MAX_SEQ, BS, P)))
+        tok = jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)
+        act = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
 
-    def tick(p, s, t, a):
-        logits, s2 = api.decode_step(p, s, t, None, active=a)
-        return jnp.argmax(logits, axis=-1), s2
+        def tick(p, s, t, a):
+            logits, s2 = api.decode_step(p, s, t, None, active=a)
+            return jnp.argmax(logits, axis=-1), s2
 
-    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
-        params, state, tok, act).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 2     # scoring + exact attention
+        compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+            params, state, tok, act).compile()
+        return compiled.as_text()
+
+
+def test_paged_decode_tick_compiles(tick_text):
+    """The whole tick compiles for the chip: the kernels are in the
+    program."""
+    assert tick_text.count("tpu_custom_call") >= 2     # scoring + exact attention
+
+
+def test_paged_decode_tick_names_its_kernels_and_stages(tick_text):
+    """The chip's tick keeps the kernels' instruction names, which a trace
+    shows as op names, and every stage scope of the tick in its ops'
+    metadata; no stage scope encloses the layer scan."""
+    import re
+    kernels = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', tick_text)
+    assert sorted(set(kernels)) == ["paged_score_estimate_pallas",
+                                    "sparse_flash_decode_paged_pallas"]
+    op_names = re.findall(r'op_name="([^"]*)"', tick_text)
+    for stage in ("salca.score", "salca.select", "salca.plan",
+                  "salca.attend", "salca.history", "kv.write", "dense"):
+        assert any(f"/{stage}/" in n for n in op_names), stage
+    loops = re.findall(r"%while\.\d+ = .* while\(.*"
+                       r'op_name="([^"]*)"', tick_text)
+    assert loops and all(re.fullmatch(r"jit\(.*?\)/while", n) for n in loops)
