@@ -2,17 +2,22 @@
 
 Three O(n) stages, no sorting:
 
-1. **Histogram generation** — count occurrences of each INT8 bin (256 bins).
-   TPU-native realization: a one-hot × ones matmul per block accumulates the
-   counts on the MXU (see DESIGN.md §2: this replaces the paper's SRAM
-   read-accumulate-write pipeline; being purely additive it has no RAW
-   hazards and — crucially for the distributed extension — histograms of
-   shards simply **add**, so one 256-element psum gives a global threshold).
+1. **Histogram generation** — count occurrences of each INT8 bin (256 bins)
+   with a per-row scatter-add (`histogram256`). Histograms are purely
+   additive, so histograms of shards simply **add**: the sharded island
+   psums one 256-element histogram per row into a global threshold, and the
+   flat `histogram_topk` uses it directly.
 2. **Threshold locating** — reverse prefix sum from bin 255 down; the first
    bin whose cumulative count reaches K is the approximate threshold.
 3. **Parallel filtering** — keep all elements ≥ threshold; compact their
    indices into a fixed-capacity buffer with a cumsum-scatter (the
    data-parallel equivalent of the paper's bitonic mask-compaction).
+
+The paged (blocked) form `histogram_topk_blocked` builds no histogram and
+scatters nothing: it finds the same threshold bit by bit by counting the
+elements at or above each candidate, and compacts through prefix sums of
+the per-block keep counts, then of one block's keep flags per output slot.
+Its result is bit-identical to the three stages above.
 """
 
 from __future__ import annotations
@@ -126,22 +131,50 @@ def histogram_topk(bins: jax.Array, k: jax.Array | int, k_cap: int) -> Selection
     return Selection(indices, mask, count, t)
 
 
+def _counted_threshold(bins: jax.Array, k: jax.Array | int) -> jax.Array:
+    """`locate_threshold(histogram256(bins), k)` over bins (..., nb, bs),
+    with no histogram: the count of elements ≥ t falls as t rises, so the
+    largest bin T with count(bins ≥ T) ≥ k is found one bit at a time from
+    the most significant down, each step one compare-and-count per row."""
+    k = jnp.asarray(k)
+    t = jnp.zeros(bins.shape[:-2], jnp.int32)
+    for bit in range(7, -1, -1):                      # the 8 bits of a bin
+        cand = t | (1 << bit)
+        at_or_above = jnp.sum(bins >= cand[..., None, None].astype(bins.dtype),
+                              axis=(-2, -1), dtype=jnp.int32)
+        t = jnp.where(at_or_above >= k, cand, t)
+    return jnp.maximum(t, 1)
+
+
 def histogram_topk_blocked(bins: jax.Array, k: jax.Array | int,
                            k_cap: int) -> Selection:
     """Block-decomposed `histogram_topk`: bins (..., nb, bs) in page order.
 
-    The 256-bin histogram is purely additive, so per-block histograms simply
-    sum into the global one (the paper's O(n) streaming accumulation, here
-    over page order; the distributed path does the same merge with a psum).
-    The threshold and the compacted indices are identical to the flat form —
-    indices come out in the *logical* (flattened) coordinate.
+    Identical to `histogram_topk` on the flattened bins — the same
+    threshold, and the compacted indices in the *logical* (flattened)
+    coordinate — with no scatter and no sort. The threshold is counted
+    (`_counted_threshold`). Output slot j lies in block b, the number of
+    blocks whose inclusive keep-count prefix is ≤ j; its rank in that block
+    is j minus the keeps before it, and its offset there is the number of
+    positions of the block's own keep prefix that are ≤ that rank. Slots
+    past the kept count read index 0, masked out.
     """
     nb, bs = bins.shape[-2], bins.shape[-1]
-    hist = jnp.sum(histogram256(bins), axis=-2)        # per-block → merge
-    t = locate_threshold(hist, k)
-    flat = bins.reshape(bins.shape[:-2] + (nb * bs,))
-    keep = flat >= t[..., None].astype(flat.dtype)
-    indices, mask, count = compact_indices(keep, k_cap)
+    t = _counted_threshold(bins, k)
+    keep = bins >= t[..., None, None].astype(bins.dtype)          # (..., nb, bs)
+    per_block = jnp.sum(keep, axis=-1, dtype=jnp.int32)            # (..., nb)
+    through = jnp.cumsum(per_block, axis=-1)                       # inclusive
+    count = jnp.minimum(through[..., -1], k_cap)
+    slot = jnp.arange(k_cap, dtype=jnp.int32)
+    passed = through[..., None, :] <= slot[:, None]                # (..., k_cap, nb)
+    block = jnp.minimum(jnp.sum(passed, axis=-1, dtype=jnp.int32), nb - 1)
+    before = jnp.sum(jnp.where(passed, per_block[..., None, :], 0), axis=-1)
+    rank = slot - before
+    row = jnp.take_along_axis(keep, block[..., None], axis=-2)     # (..., k_cap, bs)
+    row_through = jnp.cumsum(row.astype(jnp.int32), axis=-1)
+    offset = jnp.sum(row_through <= rank[..., None], axis=-1, dtype=jnp.int32)
+    mask = slot < count[..., None]
+    indices = jnp.where(mask, block * bs + offset, 0)
     return Selection(indices, mask, count, t)
 
 

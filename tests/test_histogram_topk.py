@@ -1,5 +1,8 @@
 """Property tests for the O(n) histogram Top-K (paper §3.2)."""
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -86,3 +89,51 @@ def test_masked_bins_never_selected():
     sel = ht.histogram_topk(jnp.asarray(bins), 32, k_cap=64)
     chosen = np.asarray(sel.indices)[0][np.asarray(sel.mask)[0]]
     assert np.all(chosen < 64)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _composed_blocked(bins, k, k_cap):
+    """What `histogram_topk_blocked` computes, by the histogram stages on the
+    flattened bins: per-block histograms summed, the reverse-prefix
+    threshold, the cumsum-scatter compaction."""
+    flat = bins.reshape(bins.shape[:-2] + (-1,))
+    t = ht.locate_threshold(jnp.sum(ht.histogram256(bins), axis=-2), k)
+    keep = flat >= t[..., None].astype(flat.dtype)
+    return ht.Selection(*ht.compact_indices(keep, k_cap), t)
+
+
+def _assert_same_selection(got, want):
+    for name, a, b in zip(ht.Selection._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+
+
+@pytest.mark.parametrize("top", [2, 5, 256])          # bins drawn from 0..top-1
+@pytest.mark.parametrize("nb", [1, 3, 64])
+@pytest.mark.parametrize("bs", [8, 16, 32, 128])
+def test_blocked_topk_matches_histogram_stages(bs, nb, top):
+    """The counted threshold and the block-prefix compaction give the
+    histogram stages' selection bit for bit: indices, mask, count and
+    threshold, for targets from nothing to past everything and capacities
+    from one slot to past n. Row 0 is all equal, row 1 all zero."""
+    n = nb * bs
+    rng = np.random.default_rng(bs * 1000 + nb * 10 + top)
+    bins = rng.integers(0, top, size=(2, 3, nb, bs)).astype(np.uint8)
+    bins[0, 0] = rng.integers(1, top) if top > 1 else 1
+    bins[0, 1] = 0
+    bins = jnp.asarray(bins)
+    blocked = jax.jit(ht.histogram_topk_blocked, static_argnums=2)
+    for k in (0, 1, n // 20 + 1, n, n + 5):
+        kept = int(jnp.max(_composed_blocked(bins, k, n).count))
+        for k_cap in sorted({1, max(1, kept // 2), n + 5}):
+            _assert_same_selection(blocked(bins, k, k_cap),
+                                   _composed_blocked(bins, k, k_cap))
+
+
+def test_blocked_topk_traced_per_row_k():
+    """k may be a traced array, one target per row, under jit."""
+    rng = np.random.default_rng(7)
+    nb, bs = 16, 32
+    bins = jnp.asarray(rng.integers(0, 256, size=(2, 3, nb, bs)), jnp.uint8)
+    k = jnp.asarray([[0, 1, 25], [256, nb * bs, nb * bs + 5]], jnp.int32)
+    got = jax.jit(ht.histogram_topk_blocked, static_argnums=2)(bins, k, 128)
+    _assert_same_selection(got, _composed_blocked(bins, k, 128))

@@ -146,6 +146,24 @@ def test_chunked_flash_prefill_compiles(compile_tpu):
                 ((2 * KV * G, 2048, HD), jnp.bfloat16))
 
 
+def test_paged_selection_has_no_scatter_or_sort(one_chip, no_cache):
+    """The paged selection at the decode-16k shapes compiles to compares,
+    reductions and gathers: the TPU applies scatter updates nearly one at a
+    time, and a sort feeding one costs as much again."""
+    import re
+
+    from repro.core.selection import SalcaParams, select_sparse_pattern_blocked
+    params = SalcaParams.for_seq(MAX_SEQ, 0.05)
+    assert (params.k, params.k_cap) == (819, 1024)
+    fn = functools.partial(select_sparse_pattern_blocked, params=params,
+                           block_size=BS)
+    text = jax.jit(fn).lower(
+        jax.ShapeDtypeStruct((S, KV, MAX_SEQ), jnp.float32, sharding=one_chip),
+        valid_mask=jax.ShapeDtypeStruct((S, 1, MAX_SEQ), jnp.bool_,
+                                        sharding=one_chip)).compile().as_text()
+    assert not re.findall(r"\b(scatter|sort)\(", text)
+
+
 @pytest.fixture(scope="module")
 def tick_text(one_chip, no_cache):
     """The compiled text of one whole jitted paged decode tick of qwen3-0.6b
